@@ -1,6 +1,8 @@
-"""Pallas kernel validation + arithmetic accounting (interpret mode wall
-times on CPU are NOT TPU performance; the derived column reports the
-analytic FLOP/byte profile that sizes the kernels for v5e)."""
+"""Pallas kernel validation + arithmetic accounting. The kernels run
+compiled on a TPU and in interpret mode elsewhere (``kernels.ops``
+picks); interpret-mode walls on the CPU are NOT TPU performance, and the
+derived column reports the analytic FLOP/byte profile that sizes the
+kernels for v5e."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -19,7 +21,7 @@ def run(quick: bool = True):
     a = jnp.asarray(rng.standard_normal((b, n, n)), jnp.float32)
     a = (a + jnp.swapaxes(a, -1, -2)) / 2
     x = jnp.asarray(rng.standard_normal((b, n)), jnp.float32)
-    y, al = ops.fused_matvec(a, x, interpret=True)
+    y, al = ops.fused_matvec(a, x)
     yr, alr = ref.fused_matvec(a, x)
     # alpha accumulates 512^2 f32 terms tile-wise: looser tolerance
     ok = np.allclose(y, yr, rtol=1e-4, atol=1e-3) \
@@ -38,7 +40,7 @@ def run(quick: bool = True):
     m = graph_laplacian(nn, mean_degree=8, rewire=0.0, seed=0)
     data, cols, _ = ops.dense_to_bell(m, bs=64)
     xx = jnp.asarray(rng.standard_normal(data.shape[0] * 64), jnp.float32)
-    ok = np.allclose(ops.bell_matvec(data, cols, xx, interpret=True),
+    ok = np.allclose(ops.bell_matvec(data, cols, xx),
                      ref.bell_matvec(data, cols, xx), atol=1e-4)
     nb = int(data.shape[0] * data.shape[1])
     dense_nb = int(data.shape[0] ** 2)
@@ -69,8 +71,8 @@ def run(quick: bool = True):
                     "iter vs b passes (CPU walls are not accel perf)"))
     xc = jnp.asarray(rng.standard_normal((data.shape[0] * 64, bw)),
                      jnp.float32)
-    ym = ops.bell_matvec_mrhs(data, cols, xc, interpret=True)
-    ys = jnp.stack([ops.bell_matvec(data, cols, xc[:, i], interpret=True)
+    ym = ops.bell_matvec_mrhs(data, cols, xc)
+    ys = jnp.stack([ops.bell_matvec(data, cols, xc[:, i])
                     for i in range(bw)], axis=-1)
     ok = np.allclose(ym, ys, atol=1e-4)
     blk_fl = 2 * nb * 64 * 64 * bw
@@ -91,8 +93,7 @@ def run(quick: bool = True):
     stt = gql.gql_init(wop, uu, lmn, lmx)
     lz1 = lanczos.lanczos_step(wop, stt.lz)
     out = ops.gql_update(lz1.alpha, lz1.beta, lz1.beta_prev, stt.g, stt.c,
-                         stt.delta, stt.delta_lr, stt.delta_rr, lmn, lmx,
-                         interpret=True)
+                         stt.delta, stt.delta_lr, stt.delta_rr, lmn, lmx)
     outr = ref.gql_update(lz1.alpha, lz1.beta, lz1.beta_prev, stt.g, stt.c,
                           stt.delta, stt.delta_lr, stt.delta_rr,
                           jnp.float32(lmn), jnp.float32(lmx))
@@ -100,13 +101,12 @@ def run(quick: bool = True):
     rows.append(row("pallas_gql_update_B256", 0.0,
                     f"valid={ok};fuses 8 elementwise lane-ops -> 1 VPU pass"))
 
-    # the fused per-iteration megakernel vs the reference composition
-    # (matvec + Lanczos update + recurrence as separate XLA ops): one
-    # pallas_call per GQL iteration (DESIGN.md Sec. 11)
+    # the fused step vs the reference composition: w = A v and alpha in
+    # one pallas_call, the Lanczos/recurrence tail fused by XLA
+    # (DESIGN.md Sec. 11)
     import jax as _jax
     st2 = gql.gql_step(wop, stt, lmn, lmx)  # one real step in
-    fused_fn = _jax.jit(lambda s: ops.gql_step_fused(wop, s, lmn, lmx,
-                                                     interpret=True))
+    fused_fn = _jax.jit(lambda s: ops.gql_step_fused(wop, s, lmn, lmx))
     ref_fn = _jax.jit(lambda s: gql.gql_step(wop, s, lmn, lmx))
     got, want = fused_fn(st2), ref_fn(st2)
     ok = all(np.allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
@@ -115,22 +115,21 @@ def run(quick: bool = True):
              if np.asarray(w).dtype.kind == "f")
     t_fused = time_fn(fused_fn, st2)
     t_ref = time_fn(ref_fn, st2)
-    # per iteration the fused step reads A once and keeps v/r/recurrence
-    # scalars in VMEM; the composition pays A once plus ~6 extra HBM
-    # round-trips over the (B, N) vectors for alpha/r/beta/recurrence
+    # per iteration the fused step produces alpha in the matvec's
+    # epilogue; the composition pays one extra HBM round-trip over the
+    # (B, N) vectors for it
     fl = 2 * bb * 96 * 96 + 10 * bb * 96
-    extra_hbm = 6 * 4 * bb * 96
+    extra_hbm = 2 * 4 * bb * 96
     rows.append(row("pallas_fused_step_B256_N96", t_fused * 1e6,
                     f"valid={ok};ref_us={t_ref * 1e6:.2f};"
                     f"flops={fl};vector_hbm_saved={extra_hbm};"
-                    "whole GQL iteration in one pallas_call "
-                    "(interpret-mode walls, not TPU perf)"))
+                    "w and alpha in one pallas_call "
+                    "(interpret-mode walls off the TPU)"))
 
     q = jnp.asarray(rng.standard_normal((4, 256, 64)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((4, 256, 64)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((4, 256, 64)), jnp.float32)
-    o = ops.flash_attention(q, k, v, causal=True, bt=64, bs=64,
-                            interpret=True)
+    o = ops.flash_attention(q, k, v, causal=True, bt=64, bs=64)
     ok = np.allclose(o, ref.flash_attention(q, k, v, causal=True),
                      rtol=1e-4, atol=1e-4)
     fl = 4 * 4 * 256 * 256 * 64

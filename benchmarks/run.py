@@ -19,7 +19,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import jax  # noqa: E402
 
+from repro.utils import compile_cache  # noqa: E402
+
 jax.config.update("jax_enable_x64", True)
+compile_cache.enable()
 
 MODULES = [
     "bounds_convergence",     # Fig. 1 a/b/c

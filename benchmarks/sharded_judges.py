@@ -33,7 +33,10 @@ Decisions are asserted identical across all three modes AND cadences.
 Because the device count must be fixed BEFORE jax initializes, each
 timing runs in a subprocess of this file (``--worker``) with its own
 ``XLA_FLAGS``; the parent assembles the table
-(``BENCH_sharded_judges.json`` at the repo root via run.py).
+(``BENCH_sharded_judges.json`` at the repo root via run.py). On a TPU
+the modes run in the calling process instead: a chip belongs to one
+process (a JAX child would find it held by this one), and host device
+counts do not apply there — the lane mesh spans the attached chips.
 """
 from __future__ import annotations
 
@@ -47,8 +50,9 @@ SIZES = [(256, 8), (256, 64), (1024, 8), (1024, 64)]
 CADENCES = [1, 4, 8]
 
 
-def _worker_main(mode: str, sizes, cadences) -> None:
-    """Runs inside a subprocess whose XLA_FLAGS are already set."""
+def _measure(mode: str, sizes, cadences) -> dict:
+    """One mode's timings; runs inside a subprocess whose XLA_FLAGS are
+    already set, or in-process on a TPU."""
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
     import jax
 
@@ -92,7 +96,6 @@ def _worker_main(mode: str, sizes, cadences) -> None:
                 hist.percentile(99.0))
 
     if mode in ("sharded", "floor"):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.launch.mesh import make_lane_mesh
@@ -118,12 +121,12 @@ def _worker_main(mode: str, sizes, cadences) -> None:
                 # the single-device drive independently (no gathers, no
                 # lockstep). Valid because the threshold decide is
                 # per-lane; decisions are asserted identical outside.
-                fn = jax.jit(shard_map(
+                fn = jax.jit(jax.shard_map(
                     lambda us_, ts_, op=op, solver=solver:
                     solver.judge_batch(op, us_, ts_, lam_min=lmn,
                                        lam_max=lmx),
                     mesh=mesh, in_specs=(P("lanes"), P("lanes")),
-                    out_specs=P("lanes"), check_rep=False))
+                    out_specs=P("lanes"), check_vma=False))
             else:
                 fn = jax.jit(
                     lambda us_, ts_, op=op, solver=solver:
@@ -149,7 +152,7 @@ def _worker_main(mode: str, sizes, cadences) -> None:
                 entry["hlo_collective_count"] = counts["count"]
             per_r[f"R{r}"] = entry
         out["results"][f"dense_n{n}_k{k}"] = per_r
-    print("JSON:" + json.dumps(out))
+    return out
 
 
 def _spawn(mode: str, devices: int, sizes, cadences):
@@ -173,9 +176,16 @@ def run(quick: bool = True):
     # modes at every cadence; --full adds nothing (the grid IS the
     # artifact)
     sizes = SIZES
-    single = _spawn("single", 1, sizes, [1])
-    floor = _spawn("floor", 8, sizes, CADENCES)
-    sharded = _spawn("sharded", 8, sizes, CADENCES)
+    import jax
+    if jax.default_backend() == "tpu":
+        single, floor, sharded = (_measure(mode, sizes, cadences)
+                                  for mode, cadences in (
+                                      ("single", [1]), ("floor", CADENCES),
+                                      ("sharded", CADENCES)))
+    else:
+        single = _spawn("single", 1, sizes, [1])
+        floor = _spawn("floor", 8, sizes, CADENCES)
+        sharded = _spawn("sharded", 8, sizes, CADENCES)
     rows, tables = [], {}
     for key in single["results"]:
         s1 = single["results"][key]["R1"]
@@ -241,7 +251,8 @@ def run(quick: bool = True):
 if __name__ == "__main__":
     if len(sys.argv) >= 3 and sys.argv[1] == "--worker":
         cadences = json.loads(sys.argv[4]) if len(sys.argv) > 4 else [1]
-        _worker_main(sys.argv[2], json.loads(sys.argv[3]), cadences)
+        print("JSON:" + json.dumps(
+            _measure(sys.argv[2], json.loads(sys.argv[3]), cadences)))
     else:
         rows, tables = run()
         print(json.dumps(tables, indent=1))

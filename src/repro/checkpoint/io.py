@@ -93,14 +93,22 @@ class AsyncSaver:
             self._thread = None
 
 
+def _committed_step(p: Path) -> int | None:
+    """The step of a committed ``step_XXXXXXXX`` directory, else None —
+    an in-flight async save (``step_XXXXXXXX.tmp``, which holds its
+    ``_COMMITTED`` marker just before the rename) is not a checkpoint."""
+    _, _, num = p.name.partition("step_")
+    if p.name.startswith("step_") and num.isdigit() \
+            and (p / "_COMMITTED").exists():
+        return int(num)
+    return None
+
+
 def latest_step(ckpt_dir: str | Path) -> Optional[int]:
     d = Path(ckpt_dir)
     if not d.exists():
         return None
-    steps = []
-    for p in d.iterdir():
-        if p.name.startswith("step_") and (p / "_COMMITTED").exists():
-            steps.append(int(p.name.split("_")[1]))
+    steps = [s for s in map(_committed_step, d.iterdir()) if s is not None]
     return max(steps) if steps else None
 
 
@@ -138,8 +146,7 @@ def retain(ckpt_dir: str | Path, keep: int = 3):
     d = Path(ckpt_dir)
     if not d.exists():
         return
-    steps = sorted(
-        int(p.name.split("_")[1]) for p in d.iterdir()
-        if p.name.startswith("step_") and (p / "_COMMITTED").exists())
+    steps = sorted(s for s in map(_committed_step, d.iterdir())
+                   if s is not None)
     for s in steps[:-keep]:
         shutil.rmtree(d / f"step_{s:08d}", ignore_errors=True)

@@ -193,11 +193,19 @@ def gql_step(op, st: GQLState, lam_min: Array, lam_max: Array,
     route the arithmetic through the fused Pallas kernel
     (``kernels/gql_update.py``) instead of the reference path.
     """
+    return gql_finish(st, _lz.lanczos_step(op, st.lz, basis=basis),
+                      lam_min, lam_max, recurrence)
+
+
+def gql_finish(st: GQLState, lz: _lz.LanczosState, lam_min: Array,
+               lam_max: Array, recurrence=None) -> GQLState:
+    """The recurrence and :func:`gql_assemble` for the post-step Lanczos
+    state ``lz`` — the part of :func:`gql_step` after the Lanczos step,
+    shared with the fused step kernel (``kernels/lanczos_step.py``)."""
     if recurrence is None:
         recurrence = recurrence_update
     lam_min = jnp.asarray(lam_min, st.g.dtype)
     lam_max = jnp.asarray(lam_max, st.g.dtype)
-    lz = _lz.lanczos_step(op, st.lz, basis=basis)
     # Quantities of the *new* iteration (i+1): lz.alpha / lz.beta are
     # alpha_{i+1} / beta_{i+1}; lz.beta_prev is beta_i.
     raw = recurrence(

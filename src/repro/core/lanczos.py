@@ -74,13 +74,24 @@ def lanczos_step(op, st: LanczosState, basis: Array | None = None) -> LanczosSta
     current iteration must be zero.
     """
     w = op.matvec(st.v)
-    alpha = jnp.sum(st.v * w, axis=-1)
+    return lanczos_finish(st, w, jnp.sum(st.v * w, axis=-1), basis)
+
+
+def lanczos_finish(st: LanczosState, w: Array, alpha: Array,
+                   basis: Array | None = None) -> LanczosState:
+    """The rest of a step once ``w = A v`` and ``alpha = v^T w`` are
+    known: residual, optional reorth projection, ``beta = ||r||`` and
+    :func:`lanczos_assemble`. Shared by :func:`lanczos_step` and the
+    fused step kernel (``kernels/lanczos_step.py``), which produces
+    ``w`` and ``alpha`` in one pass over A."""
     r = w - alpha[..., None] * st.v - st.beta[..., None] * st.v_prev
     if basis is not None:
         # r <- r - V^T (V r): one pass of classical Gram-Schmidt against all
         # stored vectors (zero rows contribute nothing).
-        coeff = jnp.einsum("...mn,...n->...m", basis, r)
-        r = r - jnp.einsum("...mn,...m->...n", basis, coeff)
+        coeff = jnp.einsum("...mn,...n->...m", basis, r,
+                           precision=jax.lax.Precision.HIGHEST)
+        r = r - jnp.einsum("...mn,...m->...n", basis, coeff,
+                           precision=jax.lax.Precision.HIGHEST)
     beta = jnp.linalg.norm(r, axis=-1)
     return lanczos_assemble(st, alpha, beta, r)
 

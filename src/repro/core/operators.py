@@ -22,6 +22,11 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+# f32 matmuls on the TPU default to one bf16 pass (~3 significant digits);
+# the brackets are only as good as the matvec, so every operator product
+# asks for full f32 precision (a no-op on the CPU).
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _register(cls, data_fields, meta_fields=()):
     jax.tree_util.register_dataclass(cls, data_fields=list(data_fields),
@@ -39,7 +44,7 @@ class Dense:
         return self.a.shape[-1]
 
     def matvec(self, x: Array) -> Array:
-        return jnp.einsum("...ij,...j->...i", self.a, x)
+        return jnp.einsum("...ij,...j->...i", self.a, x, precision=HIGHEST)
 
     def diag(self) -> Array:
         return jnp.diagonal(self.a, axis1=-2, axis2=-1)
@@ -175,11 +180,12 @@ class SparseBELL:
             xb = jnp.broadcast_to(xp, lanes + xp.shape[-1:])
             kern = lambda d, c, v: _kops.bell_matvec(  # noqa: E731
                 d, c, v.astype(jnp.float32), interpret=self.interpret)
-            if not lanes:
-                y = kern(self.data, self.cols, xb)
-            elif self.data.ndim == 4:
-                flat = xb.reshape((-1, xb.shape[-1]))
-                y = jax.vmap(lambda v: kern(self.data, self.cols, v))(flat)
+            if self.data.ndim == 4:
+                # one shared matrix: every lane is a column of ONE
+                # multi-RHS sweep, so each stored block is read once
+                flat = xb.reshape((-1, xb.shape[-1])).astype(jnp.float32)
+                y = _kops.bell_matvec_mrhs(self.data, self.cols, flat.T,
+                                           interpret=self.interpret).T
             else:
                 db = jnp.broadcast_to(self.data, lanes + self.data.shape[-4:])
                 cb = jnp.broadcast_to(self.cols, lanes + self.cols.shape[-2:])
@@ -349,7 +355,7 @@ def matvec_mrhs(op, x: Array) -> Array:
     """
     if isinstance(op, Dense):
         # lanes broadcast against op.a's batch dims; b rides the gemm
-        return jnp.einsum("...ij,...bj->...bi", op.a, x)
+        return jnp.einsum("...ij,...bj->...bi", op.a, x, precision=HIGHEST)
     if isinstance(op, SparseCOO):
         if op.rows.ndim == 1:
             return op.matvec(x)  # shared pattern broadcasts over (..., b)

@@ -51,8 +51,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import block as _block
 from . import gql as _gql
@@ -190,21 +189,15 @@ def init_state_sharded(solver: BIFSolver, op, u: Array, *, mesh,
         lam_min = _pad_lane_lam(lam_min, k, kp)
         lam_max = _pad_lane_lam(lam_max, k, kp)
 
+    # the init is lane-local and needs no collective: run it on the
+    # global lanes exactly as the single-device init does (bit for bit),
+    # then shard its lanes
     if cfg.block_size > 1:
-        def init_loc(op_loc, u_loc, lmn, lmx):
-            return _block.block_init(op_loc, u_loc, lmn, lmx, cfg.fn,
-                                     cfg.max_iters)
+        st = _block.block_init(op, u, lam_min, lam_max, cfg.fn,
+                               cfg.max_iters)
     else:
-        def init_loc(op_loc, u_loc, lmn, lmx):
-            return _gql.gql_init(op_loc, u_loc, lmn, lmx)
-
-    fn = shard_map(
-        init_loc,
-        mesh=mesh,
-        in_specs=(_ops.lane_specs(op, axis), P(axis))
-        + _lam_specs(lam_min, lam_max, axis),
-        out_specs=P(axis), check_rep=False)
-    st = fn(op, u, lam_min, lam_max)
+        st = _gql.gql_init(op, u, lam_min, lam_max)
+    st = jax.device_put(st, NamedSharding(mesh, P(axis)))
     # the coefficient history is elementwise over lanes; allocated
     # globally (like spectrum resolution) and sharded by the next drive.
     # Block states carry fn in the state itself (fnidx) — no coeffs.
@@ -318,14 +311,16 @@ def _drive_sharded(solver: BIFSolver, state: QuadState, decide,
             ((st_loc, coeffs_loc), nm0, cont0, jnp.zeros((), jnp.int32)))
         return st, coeffs, jnp.full((kd,), taken, jnp.int32)
 
-    fn = shard_map(
+    # called eagerly, shard_map compiles its body op by op; jitted, the
+    # drive is one compile
+    fn = jax.jit(jax.shard_map(  # quadlint: disable=QL003 -- the closure is per call; one compile per drive instead of one per op
         local_fn, mesh=mesh,
         in_specs=(_ops.lane_specs(state.op, axis),
                   jax.tree.map(lambda _: P(axis),
                                (state.st, state.coeffs)))
         + _lam_specs(state.lam_min, state.lam_max, axis)
         + (P(axis),) + tuple(P() for _ in decide_args),
-        out_specs=P(axis), check_rep=False)
+        out_specs=P(axis), check_vma=False))
     st, coeffs, taken = fn(state.op, (state.st, state.coeffs),
                            state.lam_min, state.lam_max, cap, *decide_args)
     # basis-free states use `step` only as bookkeeping; rounds-taken is

@@ -30,9 +30,10 @@ Config axes:
   * ``backend``      -- 'reference' (pure-jnp ``gql.recurrence_update``)
                         | 'pallas' (fused ``kernels/gql_update.py`` VPU
                         kernel for the scalar recurrence only)
-                        | 'fused' (``kernels/lanczos_step.py`` megakernel:
-                        matvec + Lanczos update + reorth + recurrence in
-                        ONE pallas_call per iteration; operators with no
+                        | 'fused' (``kernels/lanczos_step.py`` kernel:
+                        the matvec and alpha in ONE pallas_call per
+                        iteration, then an XLA-fused Lanczos/reorth/
+                        recurrence tail; operators with no
                         sandwich form fall back to the reference
                         composition bit-exactly);
   * ``decide_every`` -- round cadence R of the stopping rule: the loop
@@ -335,15 +336,15 @@ class BIFSolver:
     def _stepper(self):
         """One-iteration GQL step implementation per ``config.backend``:
         ``stepfn(op, st, lam_min, lam_max, basis)``. 'fused' routes the
-        whole iteration (matvec + Lanczos + reorth + recurrence) through
-        the ``kernels/lanczos_step.py`` megakernel; 'reference'/'pallas'
+        matvec and alpha through the ``kernels/lanczos_step.py`` kernel
+        and the rest through the reference tail; 'reference'/'pallas'
         compose ``gql.gql_step`` with the configured recurrence.
 
         With ``block_size > 1`` every backend steps the block recurrence
         (``block.block_step``): the per-iteration work is already
         gemm-shaped through ``operators.matvec_mrhs`` (the BELL pallas
         path uses the multi-RHS kernel), so there is no separate fused
-        megakernel — the backend knob still picks the operator's matvec
+        step kernel — the backend knob still picks the operator's matvec
         execution mode via ``configure_backend``."""
         if self.config.block_size > 1:
             def block_step(op, st, lam_min, lam_max, basis=None):
@@ -524,7 +525,7 @@ class BIFSolver:
         """One unconditional GQL step + reorth-basis / coefficient-
         history bookkeeping (no freezing — the caller applies its own
         rule). ``stepfn`` comes from :meth:`_stepper` (reference /
-        pallas-recurrence / fused-megakernel backends)."""
+        pallas-recurrence / fused-step backends)."""
         st1 = stepfn(op, st, lam_min, lam_max, basis)
         if coeffs is not None:
             coeffs = _matfun.update_coeffs(coeffs, st, st1)

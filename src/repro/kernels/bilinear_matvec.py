@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(a_ref, xj_ref, xi_ref, y_ref, al_ref, acc_y, acc_al):
     i = pl.program_id(1)
@@ -56,13 +52,16 @@ def _kernel(a_ref, xj_ref, xi_ref, y_ref, al_ref, acc_y, acc_al):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def fused_matvec(a: jax.Array, x: jax.Array, *, bm: int = 128,
-                 bn: int = 128, interpret: bool = True):
+                 bn: int = 128, interpret: bool | None = None):
     """y = A @ x and alpha = x^T A x, batched.
 
     a: (B, N, N) symmetric blocks; x: (B, N). N is zero-padded up to the
     tile size by the wrapper (zero rows/cols change neither y's valid
-    entries nor alpha).
+    entries nor alpha). ``interpret=None`` picks interpret mode off the
+    TPU only.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, n, _ = a.shape
     bm = bn = min(bm, bn, n)
     n_pad = -n % bm
@@ -92,7 +91,7 @@ def fused_matvec(a: jax.Array, x: jax.Array, *, bm: int = 128,
             pltpu.VMEM((bm,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(a, x, x)

@@ -22,10 +22,8 @@ _EPS = 1e-30
 def recurrence_math(alpha_n, beta_n, beta_p, g, c, delta, d_lr, d_rr,
                     lam_min, lam_max):
     """Traced arithmetic of one Alg. 5 recurrence update, written for
-    in-kernel use (plain jnp elementwise ops on values, not refs). Shared
-    by the standalone ``gql_update`` kernel below and the fused step
-    megakernel (``kernels/lanczos_step.py``); the oracle is
-    ``repro.core.gql.recurrence_update``."""
+    in-kernel use (plain jnp elementwise ops on values, not refs). The
+    oracle is ``repro.core.gql.recurrence_update``."""
     b2p = beta_p * beta_p
     delta_s = jnp.maximum(delta, _EPS)
     dlr_s = jnp.maximum(d_lr, _EPS)

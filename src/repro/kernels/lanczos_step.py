@@ -1,36 +1,40 @@
-"""Fused Lanczos step megakernel — one ``pallas_call`` per iteration.
+"""Fused Lanczos step kernel — the pass over A of one iteration.
 
-The runtime's hot loop previously round-tripped state through HBM
-between the lane-stacked matvec, the three-term Lanczos update, the
-reorth projection, and the ``gql_update`` recurrence (four dispatch
-points per iteration). This module fuses all of them into a single
-Pallas kernel per iteration, in two payload flavors:
-
-* **Dense tile** (``_dense_kernel``): grid ``(lane_blocks, col_blocks)``
-  streams column blocks of A through the MXU into a VMEM accumulator
-  (the ``bilinear_matvec`` pattern); the last column step runs the tail
-  — w assembly, alpha, residual, optional reorth against the banked
-  basis, beta, and the Sherman-Morrison recurrence — entirely in VMEM.
-* **Blocked-ELL** (``_bell_kernel``): the scalar-prefetch walk of
-  ``spmv_bell.py`` over ``(block_row, block_col)`` pairs, with the same
-  fused tail at the final grid step (one lane per call, vmapped).
-
-Wrapped operators reach the kernel through the diagonal-sandwich form
-(``core.operators.fused_operands``):
+A quadrature iteration needs ``w = matvec(v)`` and ``alpha = v^T w``,
+then the residual, the optional reorth projection, ``beta = ||r||`` and
+the bracket recurrence. Wrapped operators reach the kernel through the
+diagonal-sandwich form (``core.operators.fused_operands``):
 
     matvec(x) = s_out * (A @ (s_in * x)) + t * x
 
-which is closed under Masked / Shifted / Jacobi. The kernel emits *raw*
-step outputs (alpha, beta = ||r||, residual r, and the eight recurrence
-scalars); breakdown detection, freezing, and bracket collapse run
-outside through the exact same ``lanczos_assemble`` / ``gql_assemble``
-code as the reference path, so the two routes cannot drift in their
-select logic. Operators with no sandwich form (SparseCOO, MatvecFn)
-fall back to the reference composition bit-for-bit.
+which is closed under Masked / Shifted / Jacobi. One ``pallas_call``
+streams A once per lane block (every lane of a pool of up to
+``_LANE_BLOCK`` shares each tile) and runs the sandwich and ``alpha`` in
+the epilogue of each output tile, so ``w`` and ``alpha`` leave the
+kernel together. Two payload flavors:
+
+* **Dense tile** (``_dense_kernel``): grid ``(lane_blocks, row_blocks,
+  col_blocks)`` over ``(_ROW_BLOCK, _COL_BLOCK)`` tiles of A, so the
+  VMEM footprint does not grow with N.
+* **Blocked-ELL** (``_bell_kernel``): the scalar-prefetch walk of
+  ``spmv_bell.py`` over ``(block_row, block_col)`` pairs; the lane block
+  of x for each stored block is gathered by its index map.
+
+Lanes are rows of 2-D ``(K, N)`` blocks and ``alpha`` is a ``(K, 1)``
+block: Mosaic tiles the two minor dims of a block by ``(8, 128)``, so
+rank-1 per-lane blocks and a 1-D accumulator do not lower.
+
+Everything after ``alpha`` needs the whole of ``w`` (the residual needs
+the global alpha, ``beta`` the whole residual, reorth two passes over
+the basis), so it runs as plain jnp through the reference code
+(``lanczos.lanczos_finish`` / ``gql.gql_finish``) and XLA fuses it: at
+K = 64 lanes and N = 16,384 that tail moves tens of MB against the
+1 GiB of A. Operators with no sandwich form (SparseCOO, MatvecFn) fall
+back to the reference composition bit-for-bit.
 
 Off-TPU the kernels run in interpret mode in the native dtype, so the
 fused path only differs from the reference by summation order inside
-the matvec / reductions (<= 1e-12 relative on gemm-backed operators).
+the matvec and alpha (<= 1e-12 relative on gemm-backed operators).
 """
 from __future__ import annotations
 
@@ -44,237 +48,169 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core import gql as _gql
 from ..core import lanczos as _lanczos
 from ..core import operators as _operators
-from . import gql_update as _gu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-_LANE_BLOCK = 8       # lanes per grid step (dense flavor)
-_COL_BLOCK = 128      # A columns streamed per grid step (dense flavor)
-
-# benign fill values for padded lanes: delta=1 / d_rr=-1 keep every
-# guard denominator away from zero (same convention as gql_update)
-_SCALAR_FILLS = {"beta": 0.0, "g": 0.0, "c": 0.0, "delta": 1.0,
-                 "d_lr": 1.0, "d_rr": -1.0, "lam_min": 0.0, "lam_max": 1.0}
-_SCALAR_ORDER = ("beta", "g", "c", "delta", "d_lr", "d_rr",
-                 "lam_min", "lam_max")
+_LANE_BLOCK = 128     # lanes per grid step; larger pools re-stream A
+_ROW_BLOCK = 1024     # rows of A (entries of w) per output tile
+_COL_BLOCK = 512      # columns of A per streamed tile
+_ALIGN = 128          # TPU lane width: N pads to a multiple of this
 
 
-def _tail(acc, s_out, t, v, v_prev, basis, scalars):
-    """Fused step tail: finish the matvec sandwich, take the Lanczos
-    update + optional reorth, and run the recurrence. Pure traced math,
-    shared verbatim by both kernel flavors. ``scalars`` is the 8-tuple
-    in ``_SCALAR_ORDER``; returns (alpha, beta_new, r, raw8)."""
-    beta_p, g, c, delta, d_lr, d_rr, lam_min, lam_max = scalars
-    w = s_out * acc + t * v
-    alpha = jnp.sum(v * w, axis=-1)
-    r = w - alpha[..., None] * v - beta_p[..., None] * v_prev
-    if basis is not None:
-        # one classical Gram-Schmidt pass against the banked vectors
-        coeff = jax.lax.dot_general(
-            basis, r, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=r.dtype)
-        r = r - jax.lax.dot_general(
-            coeff, basis, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=r.dtype)
-    beta_new = jnp.sqrt(jnp.sum(r * r, axis=-1))
-    raw = _gu.recurrence_math(alpha, beta_new, beta_p, g, c, delta,
-                              d_lr, d_rr, lam_min, lam_max)
-    return alpha, beta_new, r, raw
+def _tile(n: int, cap: int) -> int:
+    """Largest power-of-two multiple of ``_ALIGN`` that divides ``n``
+    (itself a multiple of ``_ALIGN``) and does not exceed ``cap``."""
+    t = _ALIGN
+    while t * 2 <= cap and n % (t * 2) == 0:
+        t *= 2
+    return t
 
 
-def _write_tail(alpha, beta_new, r, raw, alpha_o, beta_o, r_o, *raw_o):
-    alpha_o[...] = alpha
-    beta_o[...] = beta_new
-    r_o[...] = r
-    for val, ref in zip(raw, raw_o):
-        ref[...] = val
+def _lane_block(k: int) -> tuple[int, int]:
+    """(lanes per block, padded lane count): one block holds every lane
+    of a pool up to ``_LANE_BLOCK``; a block equal to the full lane dim
+    needs no multiple-of-8 padding."""
+    if k <= _LANE_BLOCK:
+        return k, k
+    return _LANE_BLOCK, -(-k // _LANE_BLOCK) * _LANE_BLOCK
+
+
+def _epilogue(acc, so_ref, t_ref, v_ref, w_ref, al_ref, first):
+    """Finish one output tile: the sandwich ``w = s_out * acc + t * v``
+    and this tile's share of ``alpha = v^T w``."""
+    v = v_ref[...]
+    w = so_ref[...] * acc + t_ref[...] * v
+    w_ref[...] = w
+
+    @pl.when(first)
+    def _():
+        al_ref[...] = jnp.zeros_like(al_ref)
+
+    al_ref[...] += jnp.sum(v * w, axis=-1, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
 # Dense flavor
 
 
-def _dense_kernel(shared_a, has_basis, nj, bn, *refs):
-    a_ref, so_ref, si_ref, t_ref, v_ref, vp_ref = refs[:6]
-    scalar_refs = refs[6:14]
-    basis_ref = refs[14] if has_basis else None
-    out_refs = refs[14 + has_basis:14 + has_basis + 11]
-    acc = refs[14 + has_basis + 11]
-
-    j = pl.program_id(1)
+def _dense_kernel(nj, x_ref, a_ref, so_ref, t_ref, v_ref, w_ref, al_ref,
+                  acc):
+    i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _():
         acc[...] = jnp.zeros_like(acc)
 
-    cols = pl.ds(j * bn, bn)
-    xblk = si_ref[:, cols] * v_ref[:, cols]          # (bk, bn)
-    if shared_a:
-        # a_ref block: (N, bn); contract the column block
-        acc[...] += jax.lax.dot_general(
-            xblk, a_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=acc.dtype)
-    else:
-        # a_ref block: (bk, N, bn), batched over lanes
-        acc[...] += jax.lax.dot_general(
-            a_ref[...], xblk, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=acc.dtype)
+    # (bk, bn) lanes x (bm, bn) tile of A, contracted over the columns
+    acc[...] += jax.lax.dot_general(
+        x_ref[...], a_ref[...].astype(acc.dtype), (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=acc.dtype)
 
     @pl.when(j == nj - 1)
     def _():
-        alpha, beta_new, r, raw = _tail(
-            acc[...], so_ref[...], t_ref[...], v_ref[...], vp_ref[...],
-            basis_ref[...] if has_basis else None,
-            tuple(ref[...] for ref in scalar_refs))
-        _write_tail(alpha, beta_new, r, raw, *out_refs)
+        _epilogue(acc[...], so_ref, t_ref, v_ref, w_ref, al_ref, i == 0)
 
 
-@functools.partial(jax.jit, static_argnames=("shared_a", "interpret"))
-def fused_step_dense(a, s_out, s_in, t, v, v_prev, scalars, basis=None, *,
-                     shared_a: bool, interpret: bool = True):
-    """One fused step over (K, N) lanes with a dense A.
-
-    ``a``: (N, N) when ``shared_a`` else (K, N, N); ``scalars``: 8-tuple
-    of (K,) arrays in ``_SCALAR_ORDER``. Returns
-    ``(alpha, beta_new, r, raw8)`` with raw8 the recurrence outputs.
-    """
-    kk, n = v.shape
-    dtype = v.dtype
-    bk = min(_LANE_BLOCK, kk)
-    bn = min(_COL_BLOCK, n)
-    pad_k = -kk % bk
-    pad_n = -n % bn
-
-    def pad2(x):
-        return jnp.pad(x, ((0, pad_k), (0, pad_n))) if (pad_k or pad_n) else x
-
-    if shared_a:
-        if pad_n:
-            a = jnp.pad(a, ((0, pad_n), (0, pad_n)))
-    elif pad_k or pad_n:
-        a = jnp.pad(a, ((0, pad_k), (0, pad_n), (0, pad_n)))
-    s_out, s_in, t, v, v_prev = map(pad2, (s_out, s_in, t, v, v_prev))
-    scalars = tuple(
-        jnp.pad(s, (0, pad_k), constant_values=_SCALAR_FILLS[name])
-        if pad_k else s
-        for name, s in zip(_SCALAR_ORDER, scalars))
-    has_basis = basis is not None
-    if basis is not None and (pad_k or pad_n):
-        basis = jnp.pad(basis, ((0, pad_k), (0, 0), (0, pad_n)))
-
-    kp, np_ = kk + pad_k, n + pad_n
-    nj = np_ // bn
-    row = pl.BlockSpec((bk, np_), lambda k, j: (k, 0))
-    lane = pl.BlockSpec((bk,), lambda k, j: (k,))
-    a_spec = (pl.BlockSpec((np_, bn), lambda k, j: (0, j)) if shared_a
-              else pl.BlockSpec((bk, np_, bn), lambda k, j: (k, 0, j)))
-    in_specs = [a_spec] + [row] * 5 + [lane] * 8
-    ins = [a, s_out, s_in, t, v, v_prev, *scalars]
-    if basis is not None:
-        m = basis.shape[1]
-        in_specs.append(pl.BlockSpec((bk, m, np_), lambda k, j: (k, 0, 0)))
-        ins.append(basis)
-    out_specs = [lane, lane, row] + [lane] * 8
-    out_shape = ([jax.ShapeDtypeStruct((kp,), dtype)] * 2
-                 + [jax.ShapeDtypeStruct((kp, np_), dtype)]
-                 + [jax.ShapeDtypeStruct((kp,), dtype)] * 8)
-    extra = {}
-    if _CompilerParams is not None:
-        extra["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    outs = pl.pallas_call(
-        functools.partial(_dense_kernel, shared_a, has_basis, nj, bn),
-        grid=(kp // bk, nj),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bk, np_), dtype)],
-        interpret=interpret,
-        **extra,
-    )(*ins)
-    alpha, beta_new, r = outs[0][:kk], outs[1][:kk], outs[2][:kk, :n]
-    return alpha, beta_new, r, tuple(o[:kk] for o in outs[3:])
-
-
-# ---------------------------------------------------------------------------
-# Blocked-ELL flavor (one lane per call; vmapped by the dispatcher)
-
-
-def _bell_kernel(nr, nk, bs, *refs):
-    cols_ref, d_ref, vg_ref, sg_ref = refs[:4]
-    so_ref, t_ref, v_ref, vp_ref = refs[4:8]
-    scalar_refs = refs[8:16]
-    out_refs = refs[16:27]
-    acc = refs[27]
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        acc[...] = jnp.zeros_like(acc)
-
-    rblk = i // nk
-    xblk = sg_ref[...] * vg_ref[...]                 # gathered (bs,)
-    contrib = jax.lax.dot_general(
-        d_ref[0, 0].astype(acc.dtype), xblk, (((1,), (0,)), ((), ())),
-        preferred_element_type=acc.dtype)
-    acc[pl.ds(rblk * bs, bs)] += contrib
-
-    @pl.when(i == nr * nk - 1)
-    def _():
-        alpha, beta_new, r, raw = _tail(
-            acc[...][None], so_ref[...][None], t_ref[...][None],
-            v_ref[...][None], vp_ref[...][None], None,
-            tuple(ref[...] for ref in scalar_refs))
-        _write_tail(alpha, beta_new, r[0], raw, *out_refs)
+def _pad_lanes(arrays, k: int, kp: int, n: int, np_: int):
+    if kp == k and np_ == n:
+        return arrays
+    return [jnp.pad(x, ((0, kp - k), (0, np_ - n))) for x in arrays]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_step_bell(data, cols, s_out, s_in, t, v, v_prev, scalars, *,
-                    interpret: bool = True):
-    """One fused step for a single lane with a blocked-ELL A.
+def fused_step_dense(a, x, s_out, t, v, *, interpret: bool = True):
+    """``(w, alpha)`` for K lanes with one dense A shared by every lane.
 
-    ``data``: (R, K, bs, bs), ``cols``: (R, K); vectors are (N_pad,)
-    with N_pad = R * bs (caller zero-pads); ``scalars``: 8-tuple of
-    (1,) arrays in ``_SCALAR_ORDER``. No reorth (the dispatcher falls
-    back to the reference composition when a basis is banked).
+    ``a``: (N, N); ``x = s_in * v``, ``s_out``, ``t`` and ``v``: (K, N).
+    Returns ``w = s_out * (A @ x) + t * v`` (K, N) and ``alpha = v^T w``
+    (K,). N pads to a multiple of 128 (zero rows and columns change
+    neither), lanes to whole lane blocks.
+    """
+    kk, n = v.shape
+    dtype = v.dtype
+    bk, kp = _lane_block(kk)
+    np_ = -(-n // _ALIGN) * _ALIGN
+    bm, bn = _tile(np_, _ROW_BLOCK), _tile(np_, _COL_BLOCK)
+    if np_ != n:
+        a = jnp.pad(a, ((0, np_ - n), (0, np_ - n)))
+    x, s_out, t, v = _pad_lanes([x, s_out, t, v], kk, kp, n, np_)
+    ni, nj = np_ // bm, np_ // bn
+    row = pl.BlockSpec((bk, bm), lambda l, i, j: (l, i))
+    w, alpha = pl.pallas_call(
+        functools.partial(_dense_kernel, nj),
+        grid=(kp // bk, ni, nj),
+        in_specs=[pl.BlockSpec((bk, bn), lambda l, i, j: (l, j)),
+                  pl.BlockSpec((bm, bn), lambda l, i, j: (i, j)),
+                  row, row, row],
+        out_specs=[row, pl.BlockSpec((bk, 1), lambda l, i, j: (l, 0))],
+        out_shape=[jax.ShapeDtypeStruct((kp, np_), dtype),
+                   jax.ShapeDtypeStruct((kp, 1), dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, bm), dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(x, a, s_out, t, v)
+    return w[:kk, :n], alpha[:kk, 0]
+
+
+# ---------------------------------------------------------------------------
+# Blocked-ELL flavor
+
+
+def _bell_kernel(nk, cols_ref, x_ref, d_ref, so_ref, t_ref, v_ref, w_ref,
+                 al_ref):
+    r, k = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _():
+        w_ref[...] = jnp.zeros_like(w_ref)
+
+    # gathered (bk, bs) lane block x stored (bs, bs) block, transposed:
+    # row i of the block row is sum_j D[i, j] x[j]
+    w_ref[...] += jax.lax.dot_general(
+        x_ref[...], d_ref[0, 0].astype(w_ref.dtype),
+        (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=w_ref.dtype)
+
+    @pl.when(k == nk - 1)
+    def _():
+        _epilogue(w_ref[...], so_ref, t_ref, v_ref, w_ref, al_ref, r == 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def fused_step_bell(data, cols, x, s_out, t, v, *, interpret: bool = True):
+    """``(w, alpha)`` for K lanes with one blocked-ELL A shared by every
+    lane.
+
+    ``data``: (R, Kb, bs, bs), ``cols``: (R, Kb); ``x = s_in * v``,
+    ``s_out``, ``t`` and ``v``: (K, N) with N <= R * bs (zero-padded
+    here). Same outputs as :func:`fused_step_dense`.
     """
     nr, nk, bs, _ = data.shape
-    n_pad = nr * bs
+    kk, n = v.shape
     dtype = v.dtype
-    full = pl.BlockSpec((n_pad,), lambda i, cols: (0,))
-    one = pl.BlockSpec((1,), lambda i, cols: (0,))
-    gathered = pl.BlockSpec((bs,), lambda i, cols: (cols[i // nk, i % nk],))
+    bk, kp = _lane_block(kk)
+    np_ = nr * bs
+    x, s_out, t, v = _pad_lanes([x, s_out, t, v], kk, kp, n, np_)
+    row = pl.BlockSpec((bk, bs), lambda l, r, k, cols: (l, r))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nr * nk,),
+        grid=(kp // bk, nr, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bs, bs),
-                         lambda i, cols: (i // nk, i % nk, 0, 0)),
-            gathered, gathered,
-            full, full, full, full,
-            *([one] * 8),
+            pl.BlockSpec((bk, bs), lambda l, r, k, cols: (l, cols[r, k])),
+            pl.BlockSpec((1, 1, bs, bs), lambda l, r, k, cols: (r, k, 0, 0)),
+            row, row, row,
         ],
-        out_specs=[one, one, full] + [one] * 8,
-        scratch_shapes=[pltpu.VMEM((n_pad,), dtype)],
+        out_specs=[row, pl.BlockSpec((bk, 1), lambda l, r, k, cols: (l, 0))],
     )
-    extra = {}
-    if _CompilerParams is not None:
-        extra["compiler_params"] = _CompilerParams(
-            dimension_semantics=("arbitrary",))
-    outs = pl.pallas_call(
-        functools.partial(_bell_kernel, nr, nk, bs),
+    w, alpha = pl.pallas_call(
+        functools.partial(_bell_kernel, nk),
         grid_spec=grid_spec,
-        out_shape=([jax.ShapeDtypeStruct((1,), dtype)] * 2
-                   + [jax.ShapeDtypeStruct((n_pad,), dtype)]
-                   + [jax.ShapeDtypeStruct((1,), dtype)] * 8),
+        out_shape=[jax.ShapeDtypeStruct((kp, np_), dtype),
+                   jax.ShapeDtypeStruct((kp, 1), dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-        **extra,
-    )(cols, data, v, s_in, s_out, t, v, v_prev, *scalars)
-    alpha, beta_new, r = outs[0][0], outs[1][0], outs[2]
-    return alpha, beta_new, r, tuple(o[0] for o in outs[3:])
+    )(cols, x, data, s_out, t, v)
+    return w[:kk, :n], alpha[:kk, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -290,16 +226,16 @@ def _flatten_lanes(x, batch, trailing):
 def gql_step_fused(op, st: _gql.GQLState, lam_min, lam_max,
                    basis=None, interpret: bool | None = None
                    ) -> _gql.GQLState:
-    """Drop-in replacement for ``core.gql.gql_step`` routing the whole
-    iteration through the fused megakernel when ``op`` admits the
-    sandwich form; reference composition otherwise (bit-exact).
-    ``interpret=None`` auto-selects interpret mode off-TPU."""
+    """Drop-in replacement for ``core.gql.gql_step`` that produces ``w``
+    and ``alpha`` in the fused kernel when ``op`` admits the sandwich
+    form, and runs the reference composition otherwise (bit-exact).
+    ``interpret=None`` picks interpret mode off the TPU only.
+
+    A lane-stacked base (one A per lane) runs the kernel once per lane
+    through ``vmap``; a shared base runs it once for the whole pool."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     form = _operators.fused_operands(op)
-    if form is not None and isinstance(form[0], _operators.SparseBELL) \
-            and basis is not None:
-        form = None  # reorth not fused on the BELL flavor
     if form is None:
         return _gql.gql_step(op, st, lam_min, lam_max, basis=basis)
     base, s_out, s_in, t = form
@@ -307,53 +243,28 @@ def gql_step_fused(op, st: _gql.GQLState, lam_min, lam_max,
     dtype = st.lz.v.dtype
     batch = st.lz.v.shape[:-1]
     n = st.lz.v.shape[-1]
-    lam_min = jnp.asarray(lam_min, dtype)
-    lam_max = jnp.asarray(lam_max, dtype)
-    vecs = tuple(_flatten_lanes(jnp.asarray(x, dtype), batch, (n,))
-                 for x in (s_out, s_in, t, st.lz.v, st.lz.v_prev))
-    scalars = tuple(_flatten_lanes(jnp.asarray(x, dtype), batch, ())
-                    for x in (st.lz.beta, st.g, st.c, st.delta,
-                              st.delta_lr, st.delta_rr, lam_min, lam_max))
+    s_out, t, v = (_flatten_lanes(jnp.asarray(y, dtype), batch, (n,))
+                   for y in (s_out, t, st.lz.v))
+    x = _flatten_lanes(jnp.asarray(s_in, dtype) * st.lz.v, batch, (n,))
 
     if isinstance(base, _operators.Dense):
-        shared_a = base.a.ndim == 2
-        a = base.a if shared_a else _flatten_lanes(base.a, batch, (n, n))
-        bas = (None if basis is None
-               else _flatten_lanes(basis, batch, basis.shape[-2:]))
-        alpha, beta_new, r, raw = fused_step_dense(
-            a, *vecs, scalars, bas, shared_a=shared_a, interpret=interpret)
+        mats, ranks = (base.a,), (2,)
+        kernel = fused_step_dense
     else:
-        nr, nk, bs, _ = base.data.shape[-4:]
-        n_pad = nr * bs
-        pad = n_pad - n
+        mats, ranks = (base.data, base.cols), (4, 2)
+        kernel = fused_step_bell
+    step = functools.partial(kernel, interpret=interpret)
+    if mats[0].ndim == ranks[0]:
+        w, alpha = step(*mats, x, s_out, t, v)
+    else:
+        # one matrix per lane: each lane is a one-lane pool of its own
+        mats = tuple(_flatten_lanes(m, batch, m.shape[m.ndim - rank:])
+                     for m, rank in zip(mats, ranks))
+        w, alpha = jax.vmap(
+            lambda *args: tuple(o[0] for o in step(
+                *args[:-4], *(y[None] for y in args[-4:]))))(
+            *mats, x, s_out, t, v)
 
-        def padv(x):
-            return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
-
-        vecs_p = tuple(padv(x) for x in vecs)
-        scal_1 = tuple(s[:, None] for s in scalars)  # (K, 1) per lane
-        shared = base.data.ndim == 4
-        if shared:
-            in_axes = (None, None) + (0,) * 7
-            dat, col = base.data, base.cols
-        else:
-            in_axes = (0,) * 9
-            dat = _flatten_lanes(base.data, batch, base.data.shape[-4:])
-            col = _flatten_lanes(base.cols, batch, base.cols.shape[-2:])
-        step = jax.vmap(
-            lambda d, c, so, si, tt, vv, vp, sc: fused_step_bell(
-                d, c, so, si, tt, vv, vp, sc, interpret=interpret),
-            in_axes=(in_axes[:2] + (0, 0, 0, 0, 0, 0)))
-        alpha, beta_new, r, raw = step(
-            dat, col, vecs_p[0], vecs_p[1], vecs_p[2], vecs_p[3],
-            vecs_p[4], scal_1)
-        r = r[:, :n]
-        raw = tuple(x for x in raw)
-
-    def unflatten(x, trailing=()):
-        return x.reshape(batch + trailing)
-
-    lz = _lanczos.lanczos_assemble(
-        st.lz, unflatten(alpha), unflatten(beta_new), unflatten(r, (n,)))
-    raw = tuple(unflatten(x) for x in raw)
-    return _gql.gql_assemble(st, lz, raw)
+    lz = _lanczos.lanczos_finish(st.lz, w.reshape(batch + (n,)),
+                                 alpha.reshape(batch), basis)
+    return _gql.gql_finish(st, lz, lam_min, lam_max)

@@ -1,8 +1,7 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernel bodies execute in Python/jnp for correctness validation) and False
-on a real TPU backend.
+``interpret`` defaults to True off the TPU (the kernel bodies execute as
+jnp for correctness validation) and False on a TPU backend.
 """
 from __future__ import annotations
 
@@ -21,7 +20,7 @@ def _default_interpret() -> bool:
 
 def gql_step_fused(op, st, lam_min, lam_max, basis=None, *,
                    interpret: bool | None = None):
-    """Fused Lanczos+GQL step megakernel (one pallas_call per iteration);
+    """Fused Lanczos+GQL step (one pallas_call per iteration for w, alpha);
     falls back to the reference composition for non-sandwich operators."""
     itp = _default_interpret() if interpret is None else interpret
     return _ls.gql_step_fused(op, st, lam_min, lam_max, basis=basis,

@@ -22,10 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(cols_ref, d_ref, x_ref, o_ref):
     k = pl.program_id(1)
@@ -36,47 +32,28 @@ def _kernel(cols_ref, d_ref, x_ref, o_ref):
 
     o_ref[...] += jax.lax.dot_general(
         d_ref[0, 0], x_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def bell_matvec(data: jax.Array, cols: jax.Array, x: jax.Array, *,
                 interpret: bool = True) -> jax.Array:
-    """y = A @ x for blocked-ELL A; x: (N,) with N = R * bs."""
-    r, k, bs, _ = data.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(r, k),
-        in_specs=[
-            pl.BlockSpec((1, 1, bs, bs), lambda r, k, cols: (r, k, 0, 0)),
-            pl.BlockSpec((bs,), lambda r, k, cols: (cols[r, k],)),
-        ],
-        out_specs=pl.BlockSpec((bs,), lambda r, k, cols: (r,)),
-    )
-    extra = {}
-    if _CompilerParams is not None:
-        # the output block for step (r, k) accumulates over k: the block-row
-        # axis is parallel, the block-column walk is not
-        extra["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r * bs,), jnp.float32),
-        interpret=interpret,
-        **extra,
-    )(cols, data, x)
+    """y = A @ x for blocked-ELL A; x: (N,) with N = R * bs.
+
+    One column of :func:`bell_matvec_mrhs`: Mosaic tiles a block's two
+    minor dims by (8, 128), and a rank-1 ``(bs,)`` block does not lower,
+    so x rides as an (N, 1) column."""
+    return bell_matvec_mrhs(data, cols, x[:, None], interpret=interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bell_matvec_mrhs(data: jax.Array, cols: jax.Array, x: jax.Array, *,
                      interpret: bool = True) -> jax.Array:
     """Y = A @ X for blocked-ELL A; X: (N, m) column-stacked right-hand
-    sides, N = R * bs. Same scalar-prefetch walk as :func:`bell_matvec`
-    but each (r, k) step is a (bs, bs) @ (bs, m) MXU gemm — the m block
-    columns ride one pass over the stored blocks instead of m passes
-    (``_kernel`` is shape-agnostic over the trailing dims of x)."""
+    sides, N = R * bs. The block-column table is scalar-prefetched, so
+    each (r, k) step gathers the x rows of its stored block through the
+    index map and runs one (bs, bs) @ (bs, m) MXU gemm — the m columns
+    ride one pass over the stored blocks instead of m passes."""
     r, k, bs, _ = data.shape
     m = x.shape[-1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -88,16 +65,15 @@ def bell_matvec_mrhs(data: jax.Array, cols: jax.Array, x: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bs, m), lambda r, k, cols: (r, 0)),
     )
-    extra = {}
-    if _CompilerParams is not None:
-        extra["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r * bs, m), jnp.float32),
+        # the output block for step (r, k) accumulates over k: the
+        # block-row axis is parallel, the block-column walk is not
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        **extra,
     )(cols, data, x)
 
 
@@ -113,7 +89,8 @@ def bell_matvec_ref(data: jax.Array, cols: jax.Array, x: jax.Array
     xb = x.reshape(x.shape[:-1] + (r, bs))
     if cols.ndim == 2:
         gathered = xb[..., cols, :]                     # (..., R, K, bs)
-        y = jnp.einsum("rkij,...rkj->...ri", data.astype(x.dtype), gathered)
+        y = jnp.einsum("rkij,...rkj->...ri", data.astype(x.dtype), gathered,
+                       precision=jax.lax.Precision.HIGHEST)
     else:
         # stacked operator: gather each lane's x blocks by its own table
         # (x must carry the same leading lane dims as cols)
@@ -121,7 +98,7 @@ def bell_matvec_ref(data: jax.Array, cols: jax.Array, x: jax.Array
         gathered = jnp.take_along_axis(xb, flat_idx[..., None], axis=-2)
         gathered = gathered.reshape(cols.shape[:-2] + (r, k, bs))
         y = jnp.einsum("...rkij,...rkj->...ri", data.astype(x.dtype),
-                       gathered)
+                       gathered, precision=jax.lax.Precision.HIGHEST)
     return y.reshape(x.shape[:-1] + (r * bs,))
 
 

@@ -1,22 +1,32 @@
 """Production mesh construction (assigned: 16x16 single pod; 2x16x16
 multi-pod). A FUNCTION, not a module constant — importing this module
-never touches jax device state."""
+never touches jax device state.
+
+Every mesh here uses ``AxisType.Auto`` axes: ``jax.make_mesh`` defaults
+to ``Explicit`` axes, under which sharding constraints and gathers out
+of sharded arrays must spell out their out-shardings. The quadrature
+drivers and the sharding rules rely on the compiler's propagation."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Whatever devices exist locally (tests / smoke), data x model."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def data_axes_for(mesh) -> tuple:
@@ -33,4 +43,4 @@ def make_lane_mesh(num_devices: int | None = None, *, axis: str = "lanes"):
     virtual devices.
     """
     n = len(jax.devices()) if num_devices is None else int(num_devices)
-    return jax.make_mesh((n,), (axis,))
+    return _auto_mesh((n,), (axis,))

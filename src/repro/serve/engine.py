@@ -30,6 +30,8 @@ import numpy as np
 
 from functools import partial
 
+from jax.sharding import PartitionSpec as P
+
 from ..core import matfun as core_matfun
 from ..core import operators as core_ops
 from ..core import sharded as core_sharded
@@ -204,9 +206,9 @@ def _mixed_decide(solver, lo, hi, ts, has_t):
     return jnp.where(has_t, thr, solver.tolerance_resolved(lo, hi))
 
 
-@jax.jit
+@partial(jax.jit, static_argnames=("mesh",))
 def _pool_admit_run(solver, op, st, coeffs, us, masks, fresh, fnidx,
-                    lam_min, lam_max):
+                    lam_min, lam_max, *, mesh=None):
     """Seed the ``fresh`` lanes of the pool from (pre-masked) ``us`` /
     ``masks``; every other lane's quadrature state passes through
     untouched. ``st=None`` initializes the whole pool (unoccupied lanes
@@ -215,19 +217,34 @@ def _pool_admit_run(solver, op, st, coeffs, us, masks, fresh, fnidx,
     ``fnidx`` is the authoritative per-lane spectral-function index and
     ``coeffs`` the prior pool coefficient history, frozen the same way.
     Module-level jit shared across engines, keyed on (solver config, op
-    treedef, pool shapes)."""
+    treedef, pool shapes, mesh).
+
+    With ``mesh`` every device seeds the whole pool (replicated): the
+    init then runs on the same shapes as on one device, so a lane's
+    numbers do not depend on how the compiler would have split the pool
+    (an XLA gemm rounds differently at different lane counts), and a
+    kernel in the init needs no partitioning. The step shards the
+    lanes."""
     _obs_registry.count("serve.engine.pool_admit")
-    state = solver.init_state(core_ops.Masked(op, masks), us,
-                              lam_min=lam_min, lam_max=lam_max)
-    if st is not None:
-        state = state._replace(st=tree_freeze(state.st, st, ~fresh))
-        if coeffs is not None:
+
+    def admit(op, st, coeffs, us, masks, fresh, fnidx, lam_min, lam_max):
+        state = solver.init_state(core_ops.Masked(op, masks), us,
+                                  lam_min=lam_min, lam_max=lam_max)
+        if st is not None:
+            state = state._replace(st=tree_freeze(state.st, st, ~fresh))
+            if coeffs is not None:
+                state = state._replace(
+                    coeffs=tree_freeze(state.coeffs, coeffs, ~fresh))
+        if state.coeffs is not None and fnidx is not None:
             state = state._replace(
-                coeffs=tree_freeze(state.coeffs, coeffs, ~fresh))
-    if state.coeffs is not None and fnidx is not None:
-        state = state._replace(
-            coeffs=dataclasses.replace(state.coeffs, fnidx=fnidx))
-    return state
+                coeffs=dataclasses.replace(state.coeffs, fnidx=fnidx))
+        return state
+
+    args = (op, st, coeffs, us, masks, fresh, fnidx, lam_min, lam_max)
+    if mesh is None:
+        return admit(*args)
+    return jax.shard_map(admit, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 @jax.jit
@@ -524,6 +541,26 @@ class BIFEngine:
     def reset_stats(self) -> None:
         self._metrics.reset()
 
+    def lower_step(self):
+        """Lower one scheduler round at this engine's pool shape — the
+        program every continuous-flush round runs. ``.compile()`` of the
+        result shows which kernels the round holds (``tpu_custom_call``
+        on the chip), so a caller can tell a kernel route from a silent
+        reference fallback. Seeds a pool of zero queries to get there."""
+        p, n, dt = self.max_batch, self.op.n, self._dtype
+        cfg = self.solver.config
+        fnidx = None if cfg.fn == "inv" else \
+            jnp.full((p,), core_matfun.fn_index(cfg.fn), jnp.int32)
+        state = _pool_admit_run(
+            self.solver, self.op, None, None, jnp.zeros((p, n), dt),
+            jnp.ones((p, n), dt), jnp.ones((p,), bool), fnidx,
+            jnp.asarray(self.lam_min, dt), jnp.asarray(self.lam_max, dt),
+            mesh=self.mesh)
+        return _pool_step_run.lower(
+            self.solver, state, jnp.zeros((p,), dt), jnp.zeros((p,), bool),
+            jnp.zeros((p,), jnp.int32), n=self.chunk_iters, mesh=self.mesh,
+            axis=self.lane_axis)
+
     def _step(self, state, ts, has_t, it_cap):
         """One pool decision round (seam for tests / fault injection)."""
         return _pool_step_run(self.solver, state, ts, has_t, it_cap,
@@ -662,7 +699,7 @@ class BIFEngine:
                             jnp.asarray(us), jnp.asarray(masks),
                             jnp.asarray(fresh),
                             jnp.asarray(fnidx) if tracking else None,
-                            lam_min, lam_max)
+                            lam_min, lam_max, mesh=self.mesh)
                     else:
                         # warm-only round: every admitted lane scatters a
                         # banked state in, so skip the pool init matvec

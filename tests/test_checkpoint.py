@@ -47,6 +47,21 @@ def test_retention(tmp_path):
     assert steps == ["step_00000004", "step_00000005"]
 
 
+def test_in_flight_save_is_not_a_checkpoint(tmp_path):
+    """An async save holds its ``_COMMITTED`` marker in ``step_N.tmp``
+    just before the rename; listing and retention must skip it."""
+    t = make_tree()
+    for s in (1, 2, 3):
+        ckpt.save(tmp_path, s, t)
+    tmp = tmp_path / "step_00000004.tmp"
+    tmp.mkdir()
+    (tmp / "_COMMITTED").touch()
+    assert ckpt.latest_step(tmp_path) == 3
+    ckpt.retain(tmp_path, keep=1)
+    assert sorted(p.name for p in tmp_path.iterdir()) == [
+        "step_00000003", "step_00000004.tmp"]
+
+
 def test_async_saver(tmp_path):
     t = make_tree()
     s = ckpt.AsyncSaver()

@@ -25,7 +25,8 @@ cfg = get_arch("{arch}").reduced()
 # shrink the shape cell for CPU compile
 shapes_mod.SHAPES = dict(shapes_mod.SHAPES)
 shapes_mod.SHAPES["tiny"] = shapes_mod.ShapeCell("tiny", "{kind}", 64, 8)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model=2)   # (4, 2) data x model, Auto axes
 plan = shapi.tp_plan(data_axes=("data",), model_axis="model", fsdp=False)
 compiled, kind, (tl, tc) = dryrun._lower_and_compile(
     cfg, "tiny", mesh, plan)

@@ -1,8 +1,9 @@
-"""Fused Lanczos-step megakernel parity (DESIGN.md Sec. 11).
+"""Fused Lanczos-step kernel parity (DESIGN.md Sec. 11).
 
-``kernels/lanczos_step.py`` runs the whole quadrature iteration —
-lane-stacked matvec, three-term Lanczos update, reorth projection, and
-the GQL/Sherman-Morrison bracket recurrence — in one ``pallas_call``.
+``kernels/lanczos_step.py`` runs the pass over A of each quadrature
+iteration — the lane-stacked matvec and alpha — in one ``pallas_call``,
+and the Lanczos update, reorth projection and GQL/Sherman-Morrison
+bracket recurrence as the reference tail.
 The contract: for every sandwich-decomposable operator the fused step
 matches the reference composition (``gql.gql_step``) to 1e-12 on
 gemm-backed paths, and operators WITHOUT a sandwich form (SparseCOO)

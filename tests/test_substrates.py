@@ -67,10 +67,7 @@ def test_error_feedback_psum_converges():
     rng = np.random.default_rng(1)
     g = jnp.asarray(rng.standard_normal(256), jnp.float32)
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax exposes it under experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def step(gg, res):
