@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device,
+in a job cell (profiler trace, ``trace_reduce``)."""
+from chipbench.readers import idle_share
+
+
+def read(run):
+    return idle_share(run) if run.kind == "job" else None
