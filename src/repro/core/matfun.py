@@ -199,6 +199,17 @@ def _extension_scalars(st, lam_min, lam_max):
         st.lz.beta
 
 
+@jax.jit
+def _eigh(t):
+    """``jnp.linalg.eigh`` named ``quad.eigh`` on the device (profiler
+    framework op names): the quadrature step's own cost, apart from the
+    operator pass. The jit of its own keeps the name on eager calls too,
+    which a dispatched op would otherwise compile without; inside a
+    compiled caller it inlines, so results are unchanged."""
+    with jax.named_scope("quad.eigh"):
+        return jnp.linalg.eigh(t)
+
+
 def estimates(coeffs: CoeffHistory, st, lam_min, lam_max) -> Array:
     """All four unit-normalized quadrature estimates of
     ``e_1^T f(J) e_1`` at the current iteration, stacked on a trailing
@@ -258,7 +269,7 @@ def estimates(coeffs: CoeffHistory, st, lam_min, lam_max) -> Array:
     t = (diags[..., :, None] * eye
          + op[..., :, None] * up
          + op[..., None, :] * up.T)
-    theta, vecs = jnp.linalg.eigh(t)
+    theta, vecs = _eigh(t)
     weights = vecs[..., 0, :] ** 2                          # (..., 4, m1)
 
     # every registered f on the shared Ritz values; per-lane select

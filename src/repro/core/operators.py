@@ -14,7 +14,7 @@ stand-in for the principal submatrix A_Y (mask semantics below), and
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 from typing import Any
 
 import jax
@@ -26,6 +26,18 @@ Array = jax.Array
 # the brackets are only as good as the matvec, so every operator product
 # asks for full f32 precision (a no-op on the CPU).
 HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _pass_scope(matvec):
+    """Name the operator pass on the device: every operation a matvec
+    emits, kernel or XLA, carries ``op.matvec`` in its name stack (HLO
+    metadata, the profiler's framework op name). Metadata only: what is
+    compiled and computed is unchanged."""
+    @wraps(matvec)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("op.matvec"):
+            return matvec(*args, **kwargs)
+    return scoped
 
 
 def _register(cls, data_fields, meta_fields=()):
@@ -43,6 +55,7 @@ class Dense:
     def n(self) -> int:
         return self.a.shape[-1]
 
+    @_pass_scope
     def matvec(self, x: Array) -> Array:
         return jnp.einsum("...ij,...j->...i", self.a, x, precision=HIGHEST)
 
@@ -73,6 +86,7 @@ class SparseCOO:
     def n(self) -> int:
         return self.n_static
 
+    @_pass_scope
     def matvec(self, x: Array) -> Array:
         if self.rows.ndim == 1:
             # y[r] += v * x[c]; out-of-range rows dropped. The output
@@ -167,6 +181,7 @@ class SparseBELL:
             return self
         return dataclasses.replace(self, mode=mode, interpret=interpret)
 
+    @_pass_scope
     def matvec(self, x: Array) -> Array:
         from ..kernels import spmv_bell as _sb  # deferred: pulls in pallas
         r, _, bs, _ = self.data.shape[-4:]
@@ -243,6 +258,7 @@ class Masked:
     def n(self) -> int:
         return self.base.n
 
+    @_pass_scope
     def matvec(self, x: Array) -> Array:
         m = self.mask.astype(x.dtype)
         return m * self.base.matvec(m * x) + (1.0 - m) * x
@@ -271,6 +287,7 @@ class Shifted:
         s = jnp.asarray(self.sigma)
         return s[..., None] if s.ndim else s
 
+    @_pass_scope
     def matvec(self, x: Array) -> Array:
         return self.base.matvec(x) + self._sigma_col() * x
 
@@ -301,6 +318,7 @@ class Jacobi:
     def n(self) -> int:
         return self.base.n
 
+    @_pass_scope
     def matvec(self, x: Array) -> Array:
         return self.inv_sqrt_diag * self.base.matvec(self.inv_sqrt_diag * x)
 
@@ -326,6 +344,7 @@ class MatvecFn:
     def n(self) -> int:
         return self.n_static
 
+    @_pass_scope
     def matvec(self, x: Array) -> Array:
         return self.fn(x)
 
@@ -340,6 +359,7 @@ _register(MatvecFn, ["diag_vals"], ["fn", "n_static"])
 # Multi-vector right-hand sides (block-Krylov mode, DESIGN.md Sec. 13)
 
 
+@_pass_scope
 def matvec_mrhs(op, x: Array) -> Array:
     """y = A @ X for a row-stacked block X of shape (..., b, N) — the
     block-Lanczos workhorse. Row i of the output is ``op.matvec`` of row

@@ -64,6 +64,7 @@ from . import matfun as _matfun
 from . import operators as _ops
 from . import spectrum as _spectrum
 from .loop_utils import tree_freeze
+from ..obs import registry as _obs_registry
 
 Array = jax.Array
 
@@ -706,6 +707,9 @@ class BIFSolver:
             return jnp.any(carry[4])
 
         def body(carry):
+            # trace-time probe: counts how often the loop is traced (an
+            # eager call traces it afresh every time)
+            _obs_registry.count("core.solver.resume")
             st, basis, coeffs, step, _ = round_fn(carry)
             return st, basis, coeffs, step, needs_more(st, coeffs)
 

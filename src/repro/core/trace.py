@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import solver as _solver
+from ..obs import spans as obs_spans
 
 Array = jax.Array
 
@@ -215,58 +216,63 @@ def trace_quad(op, fn: str = "log", num_probes: Optional[int] = None, *,
     pos = -(-start // b) * b   # banked lanes end on a block boundary
     while pos < walk_total:
         stop = min(pos + chunk, walk_total)
-        us = _probes(key, pos, stop, n, dtype, exact)
-        if b > 1:
-            us = us.reshape((stop - pos) // b, b, n)
-        if mesh is None:
-            res = solver.solve_batch(op, us, lam_min=lam_min,
-                                     lam_max=lam_max)
-        else:
-            res = solver.solve_batch_sharded(op, us, mesh=mesh,
-                                             axis=lane_axis,
-                                             lam_min=lam_min,
-                                             lam_max=lam_max)
-        done_lo.append(np.asarray(res.lower))
-        done_hi.append(np.asarray(res.upper))
-        done_it.append(np.asarray(res.iterations))
+        with obs_spans.span("trace.probes"):
+            us = _probes(key, pos, stop, n, dtype, exact)
+            if b > 1:
+                us = us.reshape((stop - pos) // b, b, n)
+        with obs_spans.span("trace.solve"):
+            if mesh is None:
+                res = solver.solve_batch(op, us, lam_min=lam_min,
+                                         lam_max=lam_max)
+            else:
+                res = solver.solve_batch_sharded(op, us, mesh=mesh,
+                                                 axis=lane_axis,
+                                                 lam_min=lam_min,
+                                                 lam_max=lam_max)
+        # the host waits here for the chunk's device work
+        with obs_spans.span("trace.readback"):
+            done_lo.append(np.asarray(res.lower))
+            done_hi.append(np.asarray(res.upper))
+            done_it.append(np.asarray(res.iterations))
         pos = stop
 
-    lo = np.concatenate(done_lo) if done_lo else np.zeros((0,), dtype)
-    hi = np.concatenate(done_hi) if done_hi else np.zeros((0,), dtype)
-    it = np.concatenate(done_it) if done_it \
-        else np.zeros((0,), np.int32)
+    with obs_spans.span("trace.reduce"):
+        lo = np.concatenate(done_lo) if done_lo else np.zeros((0,), dtype)
+        hi = np.concatenate(done_hi) if done_hi else np.zeros((0,), dtype)
+        it = np.concatenate(done_it) if done_it \
+            else np.zeros((0,), np.int32)
 
-    # deterministic bracket: in exact mode the SUM over the N unit
-    # probes is tr f(A) (a true certificate; block lanes sum their b
-    # slots already, padding slots contribute exactly 0); in Hutchinson
-    # mode the MEAN over the lanes, divided by the probes-per-lane b,
-    # is the sample estimate of it. The CI is over the per-lane means
-    # mid/b — each an unbiased tr f(A) estimate (the variance-reduced
-    # block estimator: a lane averages b probes).
-    mid = 0.5 * (lo + hi)
-    if exact:
-        mean_lo, mean_hi = float(lo.sum()), float(hi.sum())
-        estimate = float(mid.sum())
-        se = 0.0
-    else:
-        lane_mid = mid / b
-        mean_lo, mean_hi = float(lo.mean() / b), float(hi.mean() / b)
-        estimate = float(lane_mid.mean())
-        se = float(np.std(lane_mid, ddof=1) / np.sqrt(len(lane_mid))) \
-            if len(lane_mid) > 1 else 0.0
-    from jax.scipy.special import ndtri
-    z = float(ndtri(0.5 + 0.5 * confidence)) if se > 0.0 else 0.0
-    half = z * se
+        # deterministic bracket: in exact mode the SUM over the N unit
+        # probes is tr f(A) (a true certificate; block lanes sum their b
+        # slots already, padding slots contribute exactly 0); in Hutchinson
+        # mode the MEAN over the lanes, divided by the probes-per-lane b,
+        # is the sample estimate of it. The CI is over the per-lane means
+        # mid/b — each an unbiased tr f(A) estimate (the variance-reduced
+        # block estimator: a lane averages b probes).
+        mid = 0.5 * (lo + hi)
+        if exact:
+            mean_lo, mean_hi = float(lo.sum()), float(hi.sum())
+            estimate = float(mid.sum())
+            se = 0.0
+        else:
+            lane_mid = mid / b
+            mean_lo, mean_hi = float(lo.mean() / b), float(hi.mean() / b)
+            estimate = float(lane_mid.mean())
+            se = float(np.std(lane_mid, ddof=1) / np.sqrt(len(lane_mid))) \
+                if len(lane_mid) > 1 else 0.0
+        from jax.scipy.special import ndtri
+        z = float(ndtri(0.5 + 0.5 * confidence)) if se > 0.0 else 0.0
+        half = z * se
 
-    new_state = TraceQuadState(fn=fn, count=total, exact=exact,
-                               probe_lower=lo, probe_upper=hi,
-                               iterations=it, key_fp=key_fp,
-                               interval=interval, block_size=b)
-    return TraceQuadResult(
-        lower=mean_lo, upper=mean_hi, estimate=estimate,
-        stat_lower=mean_lo - half, stat_upper=mean_hi + half,
-        std_error=se, num_probes=total,
-        iterations=int(it.sum()), state=new_state)
+        new_state = TraceQuadState(fn=fn, count=total, exact=exact,
+                                   probe_lower=lo, probe_upper=hi,
+                                   iterations=it, key_fp=key_fp,
+                                   interval=interval, block_size=b)
+        return TraceQuadResult(
+            lower=mean_lo, upper=mean_hi, estimate=estimate,
+            stat_lower=mean_lo - half, stat_upper=mean_hi + half,
+            std_error=se, num_probes=total,
+            iterations=int(it.sum()), state=new_state)
 
 
 def logdet_quad(op, num_probes: Optional[int] = None, *, lam_min, lam_max,

@@ -1,137 +1,28 @@
-"""Nestable monotonic-clock timing spans with Chrome-trace export.
+"""Program spans on the profiler's clock.
 
-``with span("engine.flush"):`` times a region on ``time.monotonic()``;
-completed spans accumulate as Chrome-trace "complete" events ("ph": "X",
-microsecond ts/dur) and :func:`dump_trace` writes the standard JSON
-object wrapper — load it in https://ui.perfetto.dev or
-chrome://tracing. Nesting is by timestamp containment per thread, which
-is exactly how the trace viewers reconstruct the flame graph.
+``with span("bif.round", flush=3, round=7):`` enters a
+``jax.profiler.TraceAnnotation``: while a profiler session is active
+(``jax.profiler.trace`` / ``start_trace``) the span lands in the trace
+as a host event on the same clock as the device's operations, with its
+keyword arguments as event stats; with no session it records nothing
+and costs one annotation object. The profiler session is the switch:
+there is no flag, no buffer and no exporter here.
 
-THE ATTRIBUTION CAVEAT: jax dispatch is asynchronous — a span that only
-wraps the call that LAUNCHES device work closes long before the device
-finishes, and the wall time shows up in whichever later span happens to
-block on the result (usually an innocent ``np.asarray``). Use the
-span's :meth:`Span.block_until_ready` hook on the launched values to
-charge the device time to the span that caused it::
+Device time needs no help: the trace shows each operation's interval on
+the device directly, so a span never syncs on the values it launched.
+Names carry the layer as a prefix: ``bif.`` for the serving engine's
+flush and scheduler rounds, ``trace.`` for the phases of a stochastic
+trace job; ``obs.registry.count`` adds zero-length ``retrace.<name>``
+markers. Keep arguments to small ints and short strings.
 
-    with span("engine.pool_step") as sp:
-        state, lo, hi = step(state)
-        sp.block_until_ready((lo, hi))
-
-Collection is OFF by default (a long-running service would accumulate
-events without bound) — ``set_enabled(True)`` or ``obs.enable()`` turns
-it on. Host-side only (quadlint QL008): under a jit trace the monotonic
-clock would measure TRACE time once, not run time.
+Host-side only (quadlint QL008): under a jit trace a span would mark
+trace time once per compile, not run time.
 """
 from __future__ import annotations
 
-import json
-import os
-import threading
-import time
-
-_LOCK = threading.RLock()
-_ENABLED = [False]
-_EVENTS: list = []
-_EPOCH = time.monotonic()  # trace timestamps are relative to import
-_TLS = threading.local()
+from jax.profiler import TraceAnnotation
 
 
-def set_enabled(flag: bool) -> None:
-    _ENABLED[0] = bool(flag)
-
-
-def enabled() -> bool:
-    return _ENABLED[0]
-
-
-class Span:
-    """One timed region; use via the :func:`span` context manager."""
-
-    __slots__ = ("name", "args", "_t0", "_depth", "_live")
-
-    def __init__(self, name: str, **args):
-        self.name = name
-        self.args = args
-        self._t0 = 0.0
-        self._depth = 0
-        self._live = False
-
-    def __enter__(self) -> "Span":
-        if _ENABLED[0]:
-            self._live = True
-            stack = getattr(_TLS, "stack", None)
-            if stack is None:
-                stack = _TLS.stack = []
-            self._depth = len(stack)
-            stack.append(self)
-            self._t0 = time.monotonic()
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        if not self._live:
-            return
-        t1 = time.monotonic()
-        _TLS.stack.pop()
-        self._live = False
-        args = dict(self.args)
-        args["depth"] = self._depth
-        if exc_type is not None:
-            args["error"] = exc_type.__name__
-        event = {
-            "name": self.name,
-            "cat": "obs",
-            "ph": "X",
-            "ts": (self._t0 - _EPOCH) * 1e6,
-            "dur": (t1 - self._t0) * 1e6,
-            "pid": os.getpid(),
-            "tid": threading.get_ident(),
-            "args": args,
-        }
-        with _LOCK:
-            _EVENTS.append(event)
-
-    def block_until_ready(self, value):
-        """Block on in-flight device work so it is charged to THIS span
-        (no-op when span collection is off, and when jax is absent).
-        Returns ``value`` unchanged — never alters results."""
-        if self._live:
-            try:
-                import jax
-            except ImportError:
-                return value
-            jax.block_until_ready(value)
-        return value
-
-
-def span(name: str, **args) -> Span:
-    """``with span("engine.flush", mode="continuous"): ...``"""
-    return Span(name, **args)
-
-
-def trace_events() -> list:
-    """Copy of the accumulated Chrome-trace events."""
-    with _LOCK:
-        return list(_EVENTS)
-
-
-def reset() -> None:
-    with _LOCK:
-        _EVENTS.clear()
-
-
-def dump_trace(path: str) -> dict:
-    """Write the accumulated spans as Chrome-trace JSON (object form)
-    and return the written document."""
-    with _LOCK:
-        doc = {
-            "traceEvents": list(_EVENTS),
-            "displayTimeUnit": "ms",
-            "otherData": {
-                "producer": "repro.obs.spans",
-                "clock": "monotonic-since-import",
-            },
-        }
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=1)
-    return doc
+def span(name: str, **args) -> TraceAnnotation:
+    """``with span("bif.flush", flush=k, queued=n): ...``"""
+    return TraceAnnotation(name, **args)

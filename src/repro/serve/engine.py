@@ -399,6 +399,9 @@ class BIFEngine:
         # off the same host copies.
         self._metrics_on = bool(metrics)
         self._metrics = obs_metrics.MetricsRegistry()
+        # flush sequence number: the `flush` arg that ties one flush's
+        # program spans (bif.flush, bif.round, ...) together in a trace
+        self._flushes = 0
         self.convergence_log = convergence_log
 
         def run(us, masks, ts, has_t):
@@ -587,15 +590,16 @@ class BIFEngine:
             raise ValueError(f"mode must be 'continuous' or 'lockstep', "
                              f"got {mode!r}")
         self._count("flush.count")
-        with obs_spans.span("engine.flush", mode=mode,
-                            queued=len(self._queue)):
+        self._flushes += 1
+        fid = self._flushes
+        with obs_spans.span("bif.flush", flush=fid, queued=len(self._queue)):
             if mode == "continuous":
-                return self._flush_continuous()
+                return self._flush_continuous(fid)
             return self._flush_lockstep()
 
     # -- the continuous-batching scheduler --------------------------------
 
-    def _flush_continuous(self) -> List[BIFRequest]:
+    def _flush_continuous(self, fid: int) -> List[BIFRequest]:
         cfg = self.solver.config
         if cfg.reorth or cfg.precondition != "none":
             # the stepping scheduler banks/merges plain lane states;
@@ -626,149 +630,174 @@ class BIFEngine:
         lam_max = jnp.asarray(self.lam_max, dt)
 
         try:
+            rnd = 0
             while pending or any(r is not None for r in slots):
-                # --- admit: backfill free lanes from the queue (FIFO) ---
-                fresh = np.zeros((p,), bool)
-                warm = []
-                dirty = state is None
-                now = time.monotonic()
-                for i in range(p):
-                    if slots[i] is not None:
-                        continue
-                    r = None
-                    while pending:
-                        cand = pending.pop(0)
-                        if cand.deadline is not None \
-                                and now >= cand.deadline:
-                            # already expired at the door: retire with
-                            # ZERO pool rounds burned — no lane, no
-                            # banked state, results stay cleared; FIFO
-                            # order is preserved because the queue list
-                            # itself is returned in submission order
-                            cand.certified = False
-                            cand.resolved = False
-                            cand.iterations = 0
-                            cand.state = None
-                            self._retire_obs(cand, now, expired=True)
-                            continue
-                        r = cand
+                with obs_spans.span("bif.round", flush=fid,
+                                    round=rnd) as round_span:
+                    rnd += 1
+                    # --- admit: backfill free lanes from the queue (FIFO) ---
+                    with obs_spans.span("bif.admit"):
+                        fresh = np.zeros((p,), bool)
+                        warm = []
+                        dirty = state is None
+                        now = time.monotonic()
+                        for i in range(p):
+                            if slots[i] is not None:
+                                continue
+                            r = None
+                            while pending:
+                                cand = pending.pop(0)
+                                if cand.deadline is not None \
+                                        and now >= cand.deadline:
+                                    # already expired at the door: retire
+                                    # with ZERO pool rounds burned — no
+                                    # lane, no banked state, results stay
+                                    # cleared; FIFO order is preserved
+                                    # because the queue list itself is
+                                    # returned in submission order
+                                    cand.certified = False
+                                    cand.resolved = False
+                                    cand.iterations = 0
+                                    cand.state = None
+                                    self._retire_obs(cand, now, expired=True)
+                                    continue
+                                r = cand
+                                break
+                            if r is None:
+                                continue
+                            slots[i] = r
+                            r._obs_admit_t = now
+                            self._observe("request.queue_wait_s",
+                                          now - getattr(r, "_obs_submit_t",
+                                                        now))
+                            m = np.ones((n,), dt) if r.mask is None \
+                                else np.asarray(r.mask, dt)
+                            masks[i] = m
+                            # restrict the query to the mask: Masked is
+                            # only the true submatrix system for u
+                            # supported on it (Sec. 3.2)
+                            us[i] = np.asarray(r.u, dt) * m
+                            ts[i] = 0.0 if r.t is None else r.t
+                            has_t[i] = r.t is not None
+                            fnidx[i] = core_matfun.fn_index(
+                                cfg.fn if r.fn is None else r.fn)
+                            budget = max_iters if r.max_iters is None \
+                                else max(int(r.max_iters), 0)
+                            if r.state is not None:
+                                # warm admission: resume the banked state
+                                warm.append((i, (r.state.st,
+                                                 r.state.coeffs)))
+                                caps[i] = min(int(r.state.it) + budget,
+                                              max_iters)
+                            else:
+                                fresh[i] = True
+                                caps[i] = min(budget, max_iters)
+                            dirty = True
+                    if all(r is None for r in slots):
+                        # every queued request expired at admission —
+                        # there is nothing to step (a pool round here
+                        # would burn chunk_iters x pool work on dead lanes)
                         break
-                    if r is None:
-                        continue
-                    slots[i] = r
-                    r._obs_admit_t = now
-                    self._observe("request.queue_wait_s",
-                                  now - getattr(r, "_obs_submit_t", now))
-                    m = np.ones((n,), dt) if r.mask is None \
-                        else np.asarray(r.mask, dt)
-                    masks[i] = m
-                    # restrict the query to the mask: Masked is only the
-                    # true submatrix system for u supported on it (Sec. 3.2)
-                    us[i] = np.asarray(r.u, dt) * m
-                    ts[i] = 0.0 if r.t is None else r.t
-                    has_t[i] = r.t is not None
-                    fnidx[i] = core_matfun.fn_index(
-                        cfg.fn if r.fn is None else r.fn)
-                    budget = max_iters if r.max_iters is None \
-                        else max(int(r.max_iters), 0)
-                    if r.state is not None:
-                        # warm admission: resume the banked state
-                        warm.append((i, (r.state.st, r.state.coeffs)))
-                        caps[i] = min(int(r.state.it) + budget, max_iters)
-                    else:
-                        fresh[i] = True
-                        caps[i] = min(budget, max_iters)
-                    dirty = True
-                if all(r is None for r in slots):
-                    # every queued request expired at admission — there
-                    # is nothing to step (a pool round here would burn
-                    # chunk_iters x pool work on dead lanes)
-                    break
-                if dirty:
-                    if state is None or fresh.any():
-                        # fresh lanes seed from a POOL-SHAPED init on
-                        # purpose: per-lane (1, N) inits would be cheaper
-                        # (~1 pool matvec per backfill round) but change
-                        # the matvec shape, and gemv-vs-gemm rounding
-                        # noise can flip marginal iteration counts vs the
-                        # lockstep baseline (the Sec. 6.1 caveat)
-                        state = _pool_admit_run(
-                            solver, self.op,
-                            None if state is None else state.st,
-                            None if state is None else state.coeffs,
-                            jnp.asarray(us), jnp.asarray(masks),
-                            jnp.asarray(fresh),
-                            jnp.asarray(fnidx) if tracking else None,
-                            lam_min, lam_max, mesh=self.mesh)
-                    else:
-                        # warm-only round: every admitted lane scatters a
-                        # banked state in, so skip the pool init matvec
-                        # and just rebind the masks on the pool operator
-                        state = state._replace(op=dataclasses.replace(
-                            state.op, mask=jnp.asarray(masks, dt)))
-                    for i, lane_sc in warm:
-                        st_new, coeffs_new = _pool_scatter_run(
-                            (state.st, state.coeffs), lane_sc,
-                            jnp.asarray(i))
-                        state = state._replace(st=st_new,
-                                               coeffs=coeffs_new)
+                    if dirty:
+                        with obs_spans.span("bif.init"):
+                            if state is None or fresh.any():
+                                # fresh lanes seed from a POOL-SHAPED init
+                                # on purpose: per-lane (1, N) inits would
+                                # be cheaper (~1 pool matvec per backfill
+                                # round) but change the matvec shape, and
+                                # gemv-vs-gemm rounding noise can flip
+                                # marginal iteration counts vs the
+                                # lockstep baseline (the Sec. 6.1 caveat)
+                                state = _pool_admit_run(
+                                    solver, self.op,
+                                    None if state is None else state.st,
+                                    None if state is None else state.coeffs,
+                                    jnp.asarray(us), jnp.asarray(masks),
+                                    jnp.asarray(fresh),
+                                    jnp.asarray(fnidx) if tracking else None,
+                                    lam_min, lam_max, mesh=self.mesh)
+                            else:
+                                # warm-only round: every admitted lane
+                                # scatters a banked state in, so skip the
+                                # pool init matvec and just rebind the
+                                # masks on the pool operator
+                                state = state._replace(
+                                    op=dataclasses.replace(
+                                        state.op,
+                                        mask=jnp.asarray(masks, dt)))
+                        if warm:
+                            with obs_spans.span("bif.scatter"):
+                                for i, lane_sc in warm:
+                                    st_new, coeffs_new = _pool_scatter_run(
+                                        (state.st, state.coeffs), lane_sc,
+                                        jnp.asarray(i))
+                                    state = state._replace(
+                                        st=st_new, coeffs=coeffs_new)
 
-                # --- one decision round over the whole pool ---
-                occupied = sum(1 for s in slots if s is not None)
-                self._count("flush.rounds")
-                self._observe("pool.occupancy", occupied / p)
-                with obs_spans.span("engine.pool_step",
-                                    occupied=occupied) as sp:
-                    state, lo, hi, res, dec, done, its = self._step(
-                        state, jnp.asarray(ts), jnp.asarray(has_t),
-                        jnp.asarray(caps))
-                    # charge the device work to THIS span, not to
-                    # whichever np.asarray below happens to block first
-                    sp.block_until_ready((lo, hi, res, dec, done, its))
-                lo_h, hi_h = np.asarray(lo), np.asarray(hi)
-                res_h, dec_h = np.asarray(res), np.asarray(dec)
-                done_h, it_h = np.asarray(done), np.asarray(its)
-                now = time.monotonic()
-                if self.convergence_log is not None:
-                    # host-side copies the retire loop reads anyway —
-                    # logging cannot perturb the compiled round
-                    self.convergence_log.record(lo_h, hi_h, it_h)
+                    # --- one decision round over the whole pool ---
+                    occupied = sum(1 for s in slots if s is not None)
+                    round_span.set_metadata(occupied=occupied)
+                    self._count("flush.rounds")
+                    self._observe("pool.occupancy", occupied / p)
+                    with obs_spans.span("bif.step"):
+                        state, lo, hi, res, dec, done, its = self._step(
+                            state, jnp.asarray(ts), jnp.asarray(has_t),
+                            jnp.asarray(caps))
+                    # the host waits here for the round's device work
+                    with obs_spans.span("bif.readback"):
+                        lo_h, hi_h = np.asarray(lo), np.asarray(hi)
+                        res_h, dec_h = np.asarray(res), np.asarray(dec)
+                        done_h, it_h = np.asarray(done), np.asarray(its)
+                    now = time.monotonic()
 
-                # --- retire: resolved lanes + expired budgets/deadlines ---
-                for i in range(p):
-                    r = slots[i]
-                    if r is None:
-                        continue
-                    resolved = bool(res_h[i]) or bool(done_h[i])
-                    capped = int(it_h[i]) >= min(int(caps[i]), max_iters)
-                    timed_out = r.deadline is not None and now >= r.deadline
-                    if not (resolved or capped or timed_out):
-                        continue
-                    r.lower, r.upper = float(lo_h[i]), float(hi_h[i])
-                    r.decision = bool(dec_h[i]) if r.t is not None else None
-                    r.certified = bool(res_h[i])
-                    r.resolved = resolved
-                    r.iterations = int(it_h[i])
-                    if not resolved and int(it_h[i]) < max_iters:
-                        # interrupted with headroom left: bank a per-lane
-                        # QuadState so resubmission resumes bit-exactly
-                        # (plus the premasked query, so submit() can
-                        # reject a mutated u/mask at the door)
-                        r.state = QuadState(
-                            op=dataclasses.replace(
-                                state.op, mask=state.op.mask[i]),
-                            st=jax.tree.map(lambda l: l[i], state.st),
-                            lam_min=state.lam_min, lam_max=state.lam_max,
-                            basis=None, step=state.step,
-                            coeffs=None if state.coeffs is None else
-                            jax.tree.map(lambda l: l[i], state.coeffs))
-                        r._banked_query = us[i].copy()
-                    else:
-                        r.state = None
-                    self._retire_obs(r, now,
-                                     expired=timed_out and not resolved)
-                    slots[i] = None
-                    caps[i] = 0  # freeze the vacated lane until backfill
+                    # --- retire: resolved lanes + expired budgets and
+                    # deadlines ---
+                    with obs_spans.span("bif.retire"):
+                        if self.convergence_log is not None:
+                            # host-side copies the retire loop reads
+                            # anyway — logging cannot perturb the round
+                            self.convergence_log.record(lo_h, hi_h, it_h)
+                        for i in range(p):
+                            r = slots[i]
+                            if r is None:
+                                continue
+                            resolved = bool(res_h[i]) or bool(done_h[i])
+                            capped = int(it_h[i]) >= min(int(caps[i]),
+                                                         max_iters)
+                            timed_out = r.deadline is not None \
+                                and now >= r.deadline
+                            if not (resolved or capped or timed_out):
+                                continue
+                            r.lower, r.upper = float(lo_h[i]), float(hi_h[i])
+                            r.decision = bool(dec_h[i]) \
+                                if r.t is not None else None
+                            r.certified = bool(res_h[i])
+                            r.resolved = resolved
+                            r.iterations = int(it_h[i])
+                            if not resolved and int(it_h[i]) < max_iters:
+                                # interrupted with headroom left: bank a
+                                # per-lane QuadState so resubmission
+                                # resumes bit-exactly (plus the premasked
+                                # query, so submit() can reject a mutated
+                                # u/mask at the door)
+                                r.state = QuadState(
+                                    op=dataclasses.replace(
+                                        state.op, mask=state.op.mask[i]),
+                                    st=jax.tree.map(lambda l: l[i],
+                                                    state.st),
+                                    lam_min=state.lam_min,
+                                    lam_max=state.lam_max,
+                                    basis=None, step=state.step,
+                                    coeffs=None if state.coeffs is None
+                                    else jax.tree.map(lambda l: l[i],
+                                                      state.coeffs))
+                                r._banked_query = us[i].copy()
+                            else:
+                                r.state = None
+                            self._retire_obs(
+                                r, now, expired=timed_out and not resolved)
+                            slots[i] = None
+                            caps[i] = 0  # frozen until backfill
         except Exception as e:
             # In-flight requests carry the error and are dropped (a
             # poison request must not wedge everything behind it); the
@@ -798,33 +827,35 @@ class BIFEngine:
         n, b = self.op.n, self.max_batch
         for start in range(0, len(queue), b):
             chunk = queue[start:start + b]
-            now = time.monotonic()
-            self._count("flush.rounds")
-            self._observe("pool.occupancy", len(chunk) / b)
-            for r in chunk:
-                r._obs_admit_t = now
-                self._observe("request.queue_wait_s",
-                              now - getattr(r, "_obs_submit_t", now))
             try:
-                us = np.zeros((b, n), self._dtype)
-                masks = np.ones((b, n), self._dtype)
-                ts = np.zeros((b,), self._dtype)
-                has_t = np.zeros((b,), bool)
-                for i, r in enumerate(chunk):
-                    if r.mask is not None:
-                        masks[i] = r.mask
-                    # restrict the query to the mask: Masked is only the
-                    # true submatrix system for u supported on it (Sec. 3.2)
-                    us[i] = np.asarray(r.u) * masks[i]
-                    if r.t is not None:
-                        ts[i] = r.t
-                        has_t[i] = True
-                with obs_spans.span("engine.lockstep_chunk",
-                                    size=len(chunk)) as sp:
-                    lo, hi, dec, cert, it, conv = self._run(
-                        jnp.asarray(us), jnp.asarray(masks),
-                        jnp.asarray(ts), jnp.asarray(has_t))
-                    sp.block_until_ready((lo, hi, dec, cert, it, conv))
+                with obs_spans.span("bif.admit"):
+                    now = time.monotonic()
+                    self._count("flush.rounds")
+                    self._observe("pool.occupancy", len(chunk) / b)
+                    for r in chunk:
+                        r._obs_admit_t = now
+                        self._observe("request.queue_wait_s",
+                                      now - getattr(r, "_obs_submit_t", now))
+                    us = np.zeros((b, n), self._dtype)
+                    masks = np.ones((b, n), self._dtype)
+                    ts = np.zeros((b,), self._dtype)
+                    has_t = np.zeros((b,), bool)
+                    for i, r in enumerate(chunk):
+                        if r.mask is not None:
+                            masks[i] = r.mask
+                        # restrict the query to the mask: Masked is only
+                        # the true submatrix system for u supported on it
+                        # (Sec. 3.2)
+                        us[i] = np.asarray(r.u) * masks[i]
+                        if r.t is not None:
+                            ts[i] = r.t
+                            has_t[i] = True
+                with obs_spans.span("bif.step"):
+                    out = self._run(jnp.asarray(us), jnp.asarray(masks),
+                                    jnp.asarray(ts), jnp.asarray(has_t))
+                with obs_spans.span("bif.readback"):
+                    lo, hi, dec, cert, it, conv = (np.asarray(x)
+                                                   for x in out)
             except Exception as e:
                 # keep the un-served tail, but NOT the failing chunk: a
                 # poison request requeued at the head would re-raise on
@@ -838,13 +869,14 @@ class BIFEngine:
                 self._queue = queue[start + len(chunk):] + self._queue
                 raise
             now = time.monotonic()
-            for i, r in enumerate(chunk):
-                r.lower, r.upper = float(lo[i]), float(hi[i])
-                r.decision = bool(dec[i]) if r.t is not None else None
-                r.certified = bool(cert[i])
-                r.iterations = int(it[i])
-                # same rule as the scheduler: resolved by the decision
-                # OR by Krylov exhaustion (the bracket is then exact)
-                r.resolved = bool(conv[i])
-                self._retire_obs(r, now)
+            with obs_spans.span("bif.retire"):
+                for i, r in enumerate(chunk):
+                    r.lower, r.upper = float(lo[i]), float(hi[i])
+                    r.decision = bool(dec[i]) if r.t is not None else None
+                    r.certified = bool(cert[i])
+                    r.iterations = int(it[i])
+                    # same rule as the scheduler: resolved by the decision
+                    # OR by Krylov exhaustion (the bracket is then exact)
+                    r.resolved = bool(conv[i])
+                    self._retire_obs(r, now)
         return queue

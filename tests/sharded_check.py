@@ -12,6 +12,7 @@ gemm-backed operators.
 """
 import os
 import sys
+import tempfile
 from pathlib import Path
 
 assert "--xla_force_host_platform_device_count=8" in \
@@ -254,9 +255,11 @@ def check_engine_stats_parity(mesh):
         for i, u in enumerate(us):
             t = float(true[i] * (0.9 if i % 2 else 1.1)) if i % 3 else None
             eng.submit(BIFRequest(u=u, t=t))
-    obs.spans.set_enabled(True)  # spans on for the metered engines...
-    r0, r1 = e0.flush(), e1.flush()
-    obs.spans.set_enabled(False)  # ...off for the bare one
+    # the metered engines flush inside a profiler session (spans
+    # recorded), the bare one outside it
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            r0, r1 = e0.flush(), e1.flush()
     r_off = e_off.flush()
 
     # same compiled driver, same mesh: metrics on vs off is bit-exact

@@ -388,7 +388,7 @@ def test_ql008_obs_package_attribute_path_in_jit(tmp_path):
 
         @jax.jit
         def _run(x):
-            obs.spans.trace_events()
+            obs.spans.span("step")
             return x * 2
         """)
     assert "QL008" in _rules(findings)

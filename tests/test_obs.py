@@ -3,8 +3,11 @@
 1. Metric primitives: counters/gauges, log-bucket histograms whose
    p50/p90/p99 are EXACT (nearest-rank, pinned against numpy's
    ``inverted_cdf``), global enable gate, snapshot shape.
-2. Spans: off by default, nestable, Chrome-trace events that validate
-   against the checked-in ``obs/trace_schema.json``.
+2. Spans: profiler annotations, recorded only inside a profiler
+   session, nested on the profiler's clock; the engine's ``bif.*`` and
+   the trace job's ``trace.*`` spans nest in order, the ``retrace.*``
+   markers match the registry, and no program span takes a name the
+   chip benchmark's harness uses for its own spans.
 3. Engine request metrics: a scripted mixed workload (judges, brackets,
    an expired-deadline request) produces exactly the expected counter
    ledger and histogram populations.
@@ -14,39 +17,37 @@
    failure mode (kappa=1000 Krylov exhaustion, paper Sec. 5.4) and
    stays silent on healthy reorth=True runs across kappa.
 6. THE invariant everything above rests on: telemetry never changes
-   results — metrics/spans on vs off is bit-identical across an
-   engine conformance grid (the sharded twin lives in
-   tests/sharded_check.py::check_engine_stats_parity).
+   results — metrics on vs off, and the profiler on vs off, is
+   bit-identical across an engine conformance grid (the sharded twin
+   lives in tests/sharded_check.py::check_engine_stats_parity).
 """
-import json
+import importlib.util
 import math
 import time
+from collections import Counter
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import BIFSolver, Dense, sparse_from_dense
-from repro.obs import schema as obs_schema
+from repro.core import BIFSolver, Dense, logdet_quad, sparse_from_dense
 from repro.obs.health import check_contraction
 from repro.obs.metrics import Histogram, MetricsRegistry
+from repro.obs.smoke import program_events
 from repro.serve import BIFEngine, BIFRequest
 from conftest import make_spd
 
 
 @pytest.fixture(autouse=True)
 def _obs_defaults():
-    """Every test starts from (and restores) the shipped defaults:
-    metrics on, spans off, clean span buffer."""
+    """Every test starts from (and restores) the shipped default:
+    metrics on."""
     obs.metrics.set_enabled(True)
-    obs.spans.set_enabled(False)
-    obs.spans.reset()
     yield
     obs.metrics.set_enabled(True)
-    obs.spans.set_enabled(False)
-    obs.spans.reset()
 
 
 # -- 1. metric primitives ---------------------------------------------------
@@ -120,43 +121,130 @@ def test_metrics_global_gate_stops_writes_not_reads():
 # -- 2. spans ---------------------------------------------------------------
 
 def test_spans_off_by_default_nest_and_validate_schema(tmp_path):
-    with obs.span("dead"):
+    """A span records nothing outside a profiler session; inside one it
+    is a host event on the profiler's clock, nested by containment,
+    with its arguments as event stats."""
+    with obs.span("bif.test.dead"):
         pass
-    assert obs.trace_events() == []  # collection is opt-in
-
-    obs.spans.set_enabled(True)
-    with obs.span("outer", mode="test"):
-        with obs.span("inner") as sp:
-            assert sp.block_until_ready(jnp.ones(3)) is not None
-            time.sleep(0.002)
-    events = obs.trace_events()
-    assert [e["name"] for e in events] == ["inner", "outer"]  # close order
-    inner, outer = events
-    assert inner["args"]["depth"] == 1 and outer["args"]["depth"] == 0
-    assert outer["args"]["mode"] == "test"
-    # timestamp containment is how trace viewers rebuild the flame graph
-    assert outer["ts"] <= inner["ts"]
-    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-6
-    assert inner["dur"] >= 1e3  # the 2ms sleep, in microseconds
-
-    doc = obs.dump_trace(tmp_path / "trace.json")
-    schema = json.loads(
-        (Path(obs.spans.__file__).parent / "trace_schema.json").read_text())
-    obs_schema.validate(doc, schema)
-    on_disk = json.loads((tmp_path / "trace.json").read_text())
-    assert on_disk["traceEvents"] == json.loads(json.dumps(
-        doc["traceEvents"]))
-    obs.spans.reset()
-    assert obs.trace_events() == []
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("bif.test.outer", mode="test"):
+            with obs.span("bif.test.inner"):
+                time.sleep(0.002)
+    events = program_events(tmp_path, ("bif.test.",))
+    assert [e[0] for e in events] == ["bif.test.outer", "bif.test.inner"]
+    outer, inner = events
+    assert outer[3] == {"mode": "test"}
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert inner[2] - inner[1] >= 2_000_000  # the 2 ms sleep, in ns
 
 
-def test_span_records_error_annotation():
-    obs.spans.set_enabled(True)
-    with pytest.raises(RuntimeError):
-        with obs.span("boom"):
-            raise RuntimeError("x")
-    (ev,) = obs.trace_events()
-    assert ev["args"]["error"] == "RuntimeError"
+def test_span_records_error_annotation(tmp_path):
+    """An exception leaves through the span, which still closes and is
+    recorded."""
+    with jax.profiler.trace(str(tmp_path)):
+        with pytest.raises(RuntimeError):
+            with obs.span("bif.test.boom"):
+                raise RuntimeError("x")
+        with obs.span("bif.test.after"):
+            pass
+    (boom, after) = program_events(tmp_path, ("bif.test.",))
+    assert boom[0] == "bif.test.boom" and after[0] == "bif.test.after"
+    assert boom[2] <= after[1]
+
+
+def _within(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def _traced_engine_and_job(tmp_path):
+    """A continuous flush whose budget-capped request comes back partial
+    and is resubmitted (warm admission), a lockstep flush and a small
+    logdet job, all under one profiler session."""
+    a, us, true, lam = _engine_problem(n=24, kappa=50.0, seed=3, k=7)
+    op = Dense(jnp.asarray(a))
+    eng = BIFEngine(op, solver=BIFSolver.create(max_iters=26, rtol=1e-6),
+                    max_batch=3, chunk_iters=2, **lam)
+    before = obs.retrace_counts()
+    with jax.profiler.trace(str(tmp_path)):
+        reqs = [eng.submit(BIFRequest(
+            u=u, t=float(true[i] * 1.1) if i % 2 else None,
+            max_iters=3 if i == 0 else None)) for i, u in enumerate(us)]
+        eng.flush()
+        assert reqs[0].resolved is False and reqs[0].state is not None
+        eng.submit(reqs[0])
+        eng.flush()
+        for u in us[:4]:
+            eng.submit(BIFRequest(u=u))
+        eng.flush(mode="lockstep")
+        logdet_quad(op, 6, lam_min=lam["lam_min"], lam_max=lam["lam_max"],
+                    max_iters=24, probe_chunk=3)
+    after = obs.retrace_counts()
+    delta = {k: v - before.get(k, 0) for k, v in after.items()
+             if v != before.get(k, 0)}
+    return program_events(tmp_path), delta
+
+
+def test_engine_and_job_spans_nest_in_order(tmp_path):
+    events, _ = _traced_engine_and_job(tmp_path)
+    spans = [e for e in events if not e[0].startswith("retrace.")]
+    flushes = [e for e in spans if e[0] == "bif.flush"]
+    assert [f[3]["flush"] for f in flushes] == [1, 2, 3]
+    for e in spans:
+        if e[0].startswith("bif.") and e[0] != "bif.flush":
+            assert any(_within(e, f) for f in flushes), e
+    rounds = [e for e in spans if e[0] == "bif.round"]
+    by_flush = Counter(r[3]["flush"] for r in rounds)
+    assert set(by_flush) == {1, 2}  # the lockstep flush has no rounds
+    for f in flushes[:2]:
+        mine = [r for r in rounds if _within(r, f)]
+        assert all(r[3]["flush"] == f[3]["flush"] for r in mine)
+        assert [r[3]["round"] for r in mine] == list(range(len(mine)))
+    order = ("bif.admit", "bif.init", "bif.scatter", "bif.step",
+             "bif.readback", "bif.retire")
+    seen = Counter()
+    for r in rounds:
+        assert 1 <= r[3]["occupied"] <= 3
+        phases = [e for e in spans if e[0] in order and _within(e, r)]
+        names = [e[0] for e in phases]
+        seen.update(names)
+        assert names[0] == "bif.admit", names
+        assert names[-3:] == ["bif.step", "bif.readback", "bif.retire"]
+        assert [order.index(x) for x in names] == sorted(
+            order.index(x) for x in names), names
+        for x, y in zip(phases, phases[1:]):
+            assert x[2] <= y[1]  # siblings do not overlap
+    assert seen["bif.init"] >= 2 and seen["bif.scatter"] == 1
+    lock = [e[0] for e in spans if _within(e, flushes[2])
+            and e is not flushes[2]]
+    assert lock == ["bif.admit", "bif.step", "bif.readback", "bif.retire",
+                    "bif.admit", "bif.step", "bif.readback", "bif.retire"]
+    job = [e[0] for e in spans if e[0].startswith("trace.")]
+    assert job == ["trace.probes", "trace.solve", "trace.readback"] * 2 \
+        + ["trace.reduce"]
+
+
+def test_retrace_markers_match_registry_delta(tmp_path):
+    events, delta = _traced_engine_and_job(tmp_path)
+    markers = Counter(e[0][len("retrace."):] for e in events
+                      if e[0].startswith("retrace."))
+    assert dict(markers) == delta
+    # the eager probe-chunk loop traces BIFSolver.resume once per chunk;
+    # the lockstep flush traces it once inside its jitted driver
+    assert markers["core.solver.resume"] >= 2
+
+
+def test_program_span_names_avoid_harness_spans(tmp_path):
+    """The chip benchmark's trace reduction attributes idle time to its
+    own host spans by name: a program span with one of those names
+    would be counted twice."""
+    path = Path(__file__).resolve().parent.parent / "chipbench" / "common.py"
+    spec = importlib.util.spec_from_file_location("_bench_common", path)
+    common = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(common)
+    events, _ = _traced_engine_and_job(tmp_path)
+    names = {e[0] for e in events}
+    assert {"bif.flush", "bif.round", "bif.scatter", "trace.solve"} <= names
+    assert not names & set(common.SPANS)
 
 
 # -- 3. engine request metrics ---------------------------------------------
@@ -398,12 +486,16 @@ def test_health_resolved_mask_and_stall_flag():
 
 # -- 6. telemetry is bit-invariant -----------------------------------------
 
+@pytest.mark.parametrize("profiler", [False, True],
+                         ids=["no_profiler", "profiler"])
 @pytest.mark.parametrize("op_kind", ["dense", "coo"])
-def test_engine_results_bit_identical_metrics_on_vs_off(op_kind):
+def test_engine_results_bit_identical_metrics_on_vs_off(op_kind, profiler,
+                                                        tmp_path):
     """The conformance grid: mixed judge/bracket traffic, masked lanes,
     continuous + lockstep modes — every discrete outcome AND every
     bracket float must be bit-identical with telemetry fully on
-    (metrics + spans + convergence log) vs fully off."""
+    (metrics + convergence log, and with ``profiler`` the spans recorded
+    in an active profiler session) vs fully off."""
     a, us, true, lam = _engine_problem(n=28, kappa=40.0, seed=5, k=7)
     n = a.shape[0]
     op = Dense(jnp.asarray(a)) if op_kind == "dense" \
@@ -428,10 +520,13 @@ def test_engine_results_bit_identical_metrics_on_vs_off(op_kind):
                 eng.submit(BIFRequest(u=u, t=t,
                                       mask=mask if i == len(us) - 1
                                       else None))
-            out = eng.flush(mode=mode)
+            if metrics_on and profiler:
+                with jax.profiler.trace(str(tmp_path / mode)):
+                    out = eng.flush(mode=mode)
+            else:
+                out = eng.flush(mode=mode)
         finally:
             obs.metrics.set_enabled(True)
-            obs.spans.set_enabled(False)
         return eng, out
 
     for mode in ("continuous", "lockstep"):
@@ -449,6 +544,9 @@ def test_engine_results_bit_identical_metrics_on_vs_off(op_kind):
                                    "histograms": {}}
         if mode == "continuous":
             assert eng_on.convergence_log.rounds > 0
+        if profiler:
+            names = {e[0] for e in program_events(tmp_path / mode)}
+            assert {"bif.flush", "bif.step", "bif.readback"} <= names
 
 
 def test_solver_paths_bit_identical_with_and_without_log():
