@@ -36,6 +36,8 @@ Interval semantics (the ``TraceQuadResult`` fields):
 """
 from __future__ import annotations
 
+import statistics
+from functools import partial
 from typing import NamedTuple, Optional
 
 import jax
@@ -43,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import solver as _solver
+from ..obs import registry as _obs_registry
 from ..obs import spans as obs_spans
 
 Array = jax.Array
@@ -88,18 +91,47 @@ def _rademacher_probe(key: Array, index: int, n: int, dtype) -> Array:
                                  dtype)
 
 
-def _probes(key, start: int, stop: int, n: int, dtype, exact: bool):
+@partial(jax.jit, static_argnames=("count", "exact", "block"))
+def _probe_chunk(key, start, op, *, count: int, exact: bool, block: int):
+    """Probes ``start .. start + count`` of ``op``'s size and dtype as one
+    compiled call: ``start`` is traced, so equal-length chunks share one
+    compile. Rows are row-stacked into ``block``-wide probe blocks when
+    ``block > 1``. The dtype is that of ``op.diag()``, read at trace
+    time; the diagonal itself is never computed."""
+    n, dtype = op.n, op.diag().dtype
+    idx = start + jnp.arange(count)
     if exact:
         # only the chunk's rows of I_N — never the full (N, N) identity,
         # which would defeat probe_chunk's memory bounding at large N.
         # Indices >= n (block-mode padding of the last block) produce
         # exact-zero rows, which the block init QR deflates: dead slots
         # contribute exactly 0 to the block trace.
-        return jax.nn.one_hot(jnp.arange(start, stop), n, dtype=dtype)
-    # one vmapped draw over the index range: bit-identical to per-index
-    # _rademacher_probe calls (fold_in per index), one dispatch per chunk
-    return jax.vmap(lambda i: _rademacher_probe(key, i, n, dtype))(
-        jnp.arange(start, stop))
+        us = jax.nn.one_hot(idx, n, dtype=dtype)
+    else:
+        # bit-identical to per-index _rademacher_probe calls (fold_in
+        # per index)
+        us = jax.vmap(lambda i: _rademacher_probe(key, i, n, dtype))(idx)
+    if block > 1:
+        us = us.reshape(count // block, block, n)
+    return us
+
+
+@partial(jax.jit, static_argnames=("mesh", "axis"))
+def _solve_chunk(solver, op, us, lam_min, lam_max, *, mesh=None,
+                 axis: str = "lanes"):
+    """One probe chunk's whole solve (set-up, loop, finalize) as one
+    compiled call, returning its per-lane ``(lower, upper,
+    iterations)``. Module-level like ``serve.engine._flush_run``: the
+    cache keys on (solver config, op treedef, shapes, mesh), so a fresh
+    ``BIFSolver`` and a new probe key per job reuse one compile;
+    ``lam_min``/``lam_max`` ride along as runtime values."""
+    _obs_registry.count("core.trace.solve_chunk")
+    if mesh is None:
+        res = solver.solve_batch(op, us, lam_min=lam_min, lam_max=lam_max)
+    else:
+        res = solver.solve_batch_sharded(op, us, mesh=mesh, axis=axis,
+                                         lam_min=lam_min, lam_max=lam_max)
+    return res.lower, res.upper, res.iterations
 
 
 def trace_quad(op, fn: str = "log", num_probes: Optional[int] = None, *,
@@ -205,7 +237,6 @@ def trace_quad(op, fn: str = "log", num_probes: Optional[int] = None, *,
         done_lo, done_hi, done_it = [], [], []
         start = 0
 
-    dtype = np.asarray(op.diag()).dtype
     # block mode walks padded probe indices (whole blocks; exact mode's
     # final block zero-pads past N) and rounds the chunk up to blocks
     walk_total = -(-total // b) * b
@@ -217,30 +248,23 @@ def trace_quad(op, fn: str = "log", num_probes: Optional[int] = None, *,
     while pos < walk_total:
         stop = min(pos + chunk, walk_total)
         with obs_spans.span("trace.probes"):
-            us = _probes(key, pos, stop, n, dtype, exact)
-            if b > 1:
-                us = us.reshape((stop - pos) // b, b, n)
+            us = _probe_chunk(key, pos, op, count=stop - pos,
+                              exact=exact, block=b)
         with obs_spans.span("trace.solve"):
-            if mesh is None:
-                res = solver.solve_batch(op, us, lam_min=lam_min,
-                                         lam_max=lam_max)
-            else:
-                res = solver.solve_batch_sharded(op, us, mesh=mesh,
-                                                 axis=lane_axis,
-                                                 lam_min=lam_min,
-                                                 lam_max=lam_max)
+            res = _solve_chunk(solver, op, us, lam_min, lam_max,
+                               mesh=mesh, axis=lane_axis)
         # the host waits here for the chunk's device work
         with obs_spans.span("trace.readback"):
-            done_lo.append(np.asarray(res.lower))
-            done_hi.append(np.asarray(res.upper))
-            done_it.append(np.asarray(res.iterations))
+            lo, hi, it = jax.device_get(res)   # one overlapped copy
+            done_lo.append(lo)
+            done_hi.append(hi)
+            done_it.append(it)
         pos = stop
 
     with obs_spans.span("trace.reduce"):
-        lo = np.concatenate(done_lo) if done_lo else np.zeros((0,), dtype)
-        hi = np.concatenate(done_hi) if done_hi else np.zeros((0,), dtype)
-        it = np.concatenate(done_it) if done_it \
-            else np.zeros((0,), np.int32)
+        # never empty: num_probes >= 1, banked or solved in a chunk
+        lo, hi, it = (np.concatenate(d)
+                      for d in (done_lo, done_hi, done_it))
 
         # deterministic bracket: in exact mode the SUM over the N unit
         # probes is tr f(A) (a true certificate; block lanes sum their b
@@ -260,8 +284,8 @@ def trace_quad(op, fn: str = "log", num_probes: Optional[int] = None, *,
             estimate = float(lane_mid.mean())
             se = float(np.std(lane_mid, ddof=1) / np.sqrt(len(lane_mid))) \
                 if len(lane_mid) > 1 else 0.0
-        from jax.scipy.special import ndtri
-        z = float(ndtri(0.5 + 0.5 * confidence)) if se > 0.0 else 0.0
+        z = statistics.NormalDist().inv_cdf(0.5 + 0.5 * confidence) \
+            if se > 0.0 else 0.0
         half = z * se
 
         new_state = TraceQuadState(fn=fn, count=total, exact=exact,

@@ -228,9 +228,18 @@ def test_retrace_markers_match_registry_delta(tmp_path):
     markers = Counter(e[0][len("retrace."):] for e in events
                       if e[0].startswith("retrace."))
     assert dict(markers) == delta
-    # the eager probe-chunk loop traces BIFSolver.resume once per chunk;
-    # the lockstep flush traces it once inside its jitted driver
-    assert markers["core.solver.resume"] >= 2
+    # each probe chunk of a job is one cached compiled call: a second
+    # job in the same process, with a new key, traces nothing
+    a, _, _, lam = _engine_problem(n=24, kappa=50.0, seed=3, k=7)
+    before = obs.retrace_counts()
+    with jax.profiler.trace(str(tmp_path / "again")):
+        logdet_quad(Dense(jnp.asarray(a)), 6, lam_min=lam["lam_min"],
+                    lam_max=lam["lam_max"], max_iters=24, probe_chunk=3,
+                    key=jax.random.key(5))
+    assert obs.retrace_counts() == before
+    again = program_events(tmp_path / "again")
+    assert [e[0] for e in again if e[0].startswith("retrace.")] == []
+    assert [e[0] for e in again if e[0].startswith("trace.")]
 
 
 def test_program_span_names_avoid_harness_spans(tmp_path):

@@ -12,9 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Dense, Masked, sparse_from_dense, trace_quad, \
-    logdet_quad, log_likelihood
-from repro.core.trace import _rademacher_probe
+from repro import obs
+from repro.core import BIFSolver, Dense, Masked, sparse_from_dense, \
+    trace_quad, logdet_quad, log_likelihood
+from repro.core.trace import _probe_chunk, _rademacher_probe, _solve_chunk
 from repro.train.monitor import logdet_bounds
 from conftest import make_spd
 
@@ -215,3 +216,69 @@ def test_monitor_logdet_bounds():
     assert r.lower <= true + 1e-4 * scale
     assert r.upper >= true - 1e-4 * scale
     assert abs(r.estimate - true) <= 1e-2 * scale
+
+
+def _solve_chunk_traces() -> int:
+    return obs.retrace_counts().get("core.trace.solve_chunk", 0)
+
+
+def test_repeated_jobs_trace_nothing_after_the_first():
+    """Back-to-back jobs with a new probe key and a new solver object
+    each time reuse one compiled probe draw and chunk solve: only the
+    first call may trace."""
+    a, _, _, lmn, lmx = _problem(n=26, seed=4)
+    op = Dense(jnp.asarray(a, jnp.float32))
+    kw = dict(lam_min=lmn, lam_max=lmx)
+    logdet_quad(op, 12, key=jax.random.key(0),
+                solver=BIFSolver.create(max_iters=32, rtol=1e-4), **kw)
+    before = obs.retrace_counts()
+    for k in range(1, 4):
+        r = logdet_quad(op, 12, key=jax.random.key(k),
+                        solver=BIFSolver.create(max_iters=32, rtol=1e-4),
+                        **kw)
+        assert r.lower <= r.upper
+    assert obs.retrace_counts() == before
+
+
+def test_exact_mode_equal_chunks_trace_the_solve_once():
+    """Exact unit-probe mode in equal chunks compiles one chunk solve
+    for all of them; a shorter last chunk compiles once more."""
+    a, w, _, lmn, lmx = _problem(n=30, seed=6)
+    op = Dense(jnp.asarray(a))
+    true = float(np.sum(np.log(w)))
+    t0 = _solve_chunk_traces()
+    r = trace_quad(op, "log", None, lam_min=lmn, lam_max=lmx,
+                   probe_chunk=10)       # 3 chunks of 10
+    assert _solve_chunk_traces() - t0 == 1
+    assert r.lower <= true <= r.upper
+    t1 = _solve_chunk_traces()
+    r8 = trace_quad(op, "log", None, lam_min=lmn, lam_max=lmx,
+                    probe_chunk=8)       # 8, 8, 8, then 6
+    assert _solve_chunk_traces() - t1 == 2
+    assert r8.lower <= true <= r8.upper
+    np.testing.assert_allclose(r8.state.probe_lower, r.state.probe_lower,
+                               rtol=1e-12)
+
+
+@pytest.mark.parametrize("fn", ["log", "inv"])
+def test_compiled_chunk_matches_eager_solve_batch(fn):
+    """The compiled chunk call returns what an eager ``solve_batch``
+    returns on the same probes, to float32 rounding, with the same
+    iteration counts."""
+    a, _, _, lmn, lmx = _problem(n=24, seed=8)
+    op = Dense(jnp.asarray(a, jnp.float32))
+    solver = BIFSolver.create(max_iters=32, rtol=1e-4, fn=fn)
+    us = _probe_chunk(jax.random.key(21), 0, op, count=8, exact=False,
+                      block=1)
+    want = np.stack([np.asarray(_rademacher_probe(jax.random.key(21), i,
+                                                  24, jnp.float32))
+                     for i in range(8)])
+    np.testing.assert_array_equal(np.asarray(us), want)
+    lo, hi, it = _solve_chunk(solver, op, us, lmn, lmx)
+    eager = solver.solve_batch(op, us, lam_min=lmn, lam_max=lmx)
+    np.testing.assert_allclose(np.asarray(lo), np.asarray(eager.lower),
+                               rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(hi), np.asarray(eager.upper),
+                               rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(it),
+                                  np.asarray(eager.iterations))
